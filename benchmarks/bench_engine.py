@@ -33,11 +33,6 @@ Usage: PYTHONPATH=src python -m benchmarks.run --bench engine
 
 from __future__ import annotations
 
-import json
-import os
-import re
-import subprocess
-import sys
 import time
 
 import jax
@@ -46,7 +41,7 @@ from benchmarks.timing import write_bench_json
 from repro.core import _boolref
 from repro.core.coherence import LazyPIMConfig, _lazypim_acc
 from repro.core.mechanisms import ACC_FNS
-from repro.sim import _traceref, engine, synth
+from repro.sim import _traceref, engine, mesh, synth
 from repro.sim.costmodel import HWParams
 from repro.sim.engine import (
     run_all,
@@ -64,18 +59,31 @@ STEADY_WORKLOADS = (("pagerank", "arxiv"), ("htap128", None))
 SWEEP_POINTS = 4
 SAMPLES = 5
 
-# Trace-synthesis throughput cases: one per family plus a >=1M-line large
-# instance (more kernels × wider windows — the regime the on-device
-# generator exists for; the numpy reference loops over every window).
+# htap128-large: 1,010,524 trace lines (the 1 Mi-line bucket), the largest
+# instance the repo runs — more kernels × wider windows, the regime the
+# on-device generator exists for, and big enough that per-device scan work
+# dominates shard_map dispatch cost.
+LARGE_WORKLOAD_KW = dict(scale=0.06, num_kernels=24, windows_per_kernel=16)
+LARGE_LANES = 8
+
+# Trace-synthesis throughput cases: one per family plus the large instance
+# (the numpy reference loops over every window).
 SYNTH_CASES = (
     ("pagerank", "enron", {}),
     ("htap256", None, {}),
     ("bfs", "enron", {}),
     ("htap_stream", None, {}),
     ("mtmix", "enron", {}),
-    ("htap128", None, dict(scale=0.06, num_kernels=24, windows_per_kernel=16,
-                           label="htap128-large")),
+    ("htap128", None, dict(LARGE_WORKLOAD_KW, label="htap128-large")),
 )
+
+
+def large_study(lanes: int = LARGE_LANES) -> Study:
+    """htap128-large over a ``lanes``-point off-chip-bandwidth grid, every
+    mechanism: one 1 Mi-line bucket with ``lanes`` stacked lanes."""
+    return Study(
+        workloads=[workload("htap128", **LARGE_WORKLOAD_KW)],
+        hw=grid(offchip_bw_gbs=[float(16 * (i + 1)) for i in range(lanes)]))
 
 
 def _steady_seconds(fn, *args) -> float:
@@ -318,29 +326,56 @@ def bench_trace_synth() -> dict:
 MESH_DEVICE_COUNTS = (1, 2, 4)
 
 
-def bench_mesh_scaling(device_counts=MESH_DEVICE_COUNTS) -> dict:
-    """Sharded-dispatch throughput vs simulated device count: lanes/sec of
-    a >=1M-line htap128 bucket with 8 stacked lanes at 1/2/4 simulated CPU
-    devices.  The device count is baked into XLA at backend init, so each
-    point runs in its own subprocess (``benchmarks.mesh_worker``) with
-    ``XLA_FORCE_HOST_PLATFORM_DEVICE_COUNT`` set; every worker also
-    cross-checks ``Study.plan()``'s compile prediction at its device count
-    (``check_budget.check_mesh`` gates the committed record on that)."""
-    from repro.sim.mesh import MESH_ENV_VAR, _XLA_FLAG
+def bench_mesh_leg(study: Study, devices: int) -> dict:
+    """One point of the mesh-scaling curve: run ``study`` (one bucket) at
+    ``devices``, cold then warm, and cross-check ``Study.plan()``'s compile
+    prediction against the measured jit-cache deltas (every device count
+    compiles its own mesh variant, so the cold run is a cold compile)."""
+    plan = study.plan(devices=devices)
+    (bucket,) = plan.buckets
+    before = sweep_cache_sizes()
+    t0 = time.perf_counter()
+    study.run(devices=devices)
+    cold_s = time.perf_counter() - t0
+    after = sweep_cache_sizes()
+    measured = {m: after[m] - before[m] for m in after}
 
-    legs = {}
-    for d in device_counts:
-        env = dict(os.environ)
-        env[MESH_ENV_VAR] = str(d)
-        # The parent may have pinned its own count into XLA_FLAGS; strip it
-        # so the worker's env var (read at repro.sim.mesh import) wins.
-        if "XLA_FLAGS" in env:
-            env["XLA_FLAGS"] = re.sub(rf"{_XLA_FLAG}=\d+", "",
-                                      env["XLA_FLAGS"]).strip()
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.mesh_worker", str(d)],
-            env=env, capture_output=True, text=True, check=True)
-        legs[str(d)] = json.loads(proc.stdout.strip().splitlines()[-1])
+    t0 = time.perf_counter()
+    study.run(devices=devices)
+    warm_s = time.perf_counter() - t0
+    return {
+        "devices": devices,
+        "visible_devices": mesh.available_devices(),
+        "lanes": study.num_points,
+        "padded_lanes": bucket["padded_lanes"],
+        "routed_devices": bucket["devices"],
+        "bucket_num_lines": bucket["num_lines"],
+        "plan_compiles_per_mechanism": plan.compiles_per_mechanism,
+        "measured_compiles_per_mechanism": measured,
+        "plan_matches_measured": measured == plan.compiles_per_mechanism,
+        "cold_wall_s": cold_s,
+        "warm_wall_s": warm_s,
+        # One "lane" = one (workload, hw) point simulated through every
+        # mechanism; warm wall excludes compiles, so this is the scaling
+        # quantity (same lane work at every device count — 8 % d == 0, no
+        # padding confound).
+        "lanes_per_sec": study.num_points / warm_s,
+    }
+
+
+def bench_mesh_scaling(device_counts=MESH_DEVICE_COUNTS) -> dict:
+    """Sharded-dispatch throughput vs device count: lanes/sec of the large
+    study (8 lanes in the 1 Mi-line bucket) at every count in
+    ``device_counts`` that this process can see, all in this one process
+    (a chip belongs to one process).  The device count is fixed at backend
+    init, so on CPU force it before launch
+    (``XLA_FORCE_HOST_PLATFORM_DEVICE_COUNT=4``); ``check_budget.check_mesh``
+    gates the committed record on plan == measured at every count."""
+    study = large_study()
+    study.traces()  # trace synthesis outside every timed region
+    visible = mesh.available_devices()
+    legs = {str(d): bench_mesh_leg(study, d)
+            for d in device_counts if d <= visible}
     base = legs[str(device_counts[0])]["lanes_per_sec"]
     return {
         **legs,
@@ -364,8 +399,8 @@ def run() -> dict:
         "fig7_end_to_end_extended": bench_fig7_wall(hw),
         "hw_sweep": bench_sweep(hw, cfg),
         "trace_synth": bench_trace_synth(),
-        # Subprocess-isolated (own XLA device counts): parent jit caches
-        # are irrelevant, so order doesn't matter.
+        # Its own bucket geometry and mesh variants: cold compiles
+        # whatever ran before.
         "mesh_scaling": bench_mesh_scaling(),
     }
 
@@ -398,7 +433,7 @@ def main():
         print(f"synth,{name},lines,{r['num_lines']},jax_ms,{r['jax_ms']:.2f},"
               f"ref_ms,{r['ref_ms']:.2f},speedup,{r['speedup']:.1f}")
     ms = results["mesh_scaling"]
-    for d in map(str, MESH_DEVICE_COUNTS):
+    for d in ms["scaling_vs_1_device"]:
         leg = ms[d]
         print(f"mesh,{d}dev,lanes_per_sec,{leg['lanes_per_sec']:.4f},"
               f"plan_matches,{leg['plan_matches_measured']},"

@@ -33,7 +33,6 @@ Two legs, one ``BENCH_serve.json`` record:
 
 from __future__ import annotations
 
-import tempfile
 import time
 
 import numpy as np
@@ -44,6 +43,7 @@ from repro.serve import (
     ChaosMonkey,
     ServeConfig,
     StudyServer,
+    fresh_serve_dir,
     make_storm,
     restart_server,
 )
@@ -64,7 +64,7 @@ BASE_SPECS = [
 
 
 def bench_storm() -> dict:
-    cache_dir = tempfile.mkdtemp(prefix="repro-serve-storm-")
+    cache_dir = str(fresh_serve_dir("bench-storm"))
     # Real-time chaos: the hang must outlive the heartbeat timeout for
     # detection; the timeout must in turn outlive legitimate inter-beat
     # gaps (trace synthesis before the first dispatch of a request).
@@ -269,7 +269,7 @@ def bench_policy() -> dict:
 def bench_warm_restart() -> dict:
     from benchmarks.fig7_speedup import study as fig7_study
 
-    cache_dir = tempfile.mkdtemp(prefix="repro-serve-warm-")
+    cache_dir = str(fresh_serve_dir("bench-warm"))
     cfg = ServeConfig(default_deadline_s=3600.0, cache_dir=cache_dir)
 
     srv = StudyServer(cfg)
@@ -296,7 +296,6 @@ def bench_warm_restart() -> dict:
         f"warm restart recompiled {new_compiles} scans"
     return {
         "manifest_entries": len(manifest),
-        "persistent_cache": srv2.warm.persistent,
         "cold_serve_s": round(cold_s, 2),
         "warm_boot_s": round(warm_boot_s, 2),
         "warm_serve_s": round(warm_serve_s, 2),

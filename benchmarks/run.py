@@ -7,7 +7,8 @@ Prints each figure's CSV block plus the headline-claims summary from the
 calibration harness (benchmarks.calibrate).  ``--bench`` runs a named
 microbench suite (``signatures`` or ``engine``), each writing its
 ``BENCH_<name>.json`` at the repo root via the shared
-``benchmarks.timing.write_bench_json`` helper.
+``benchmarks.timing.write_bench_json`` helper.  JAX's persistent compile
+cache is placed by :func:`repro.runtime.compile_cache.setup_compile_cache`.
 """
 
 from __future__ import annotations
@@ -46,6 +47,10 @@ def main() -> None:
     args = ap.parse_args()
 
     import importlib
+
+    from repro.runtime.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
 
     if args.bench:
         name = BENCHES[args.bench]

@@ -12,7 +12,6 @@ wrong results.
 
 import argparse
 import sys
-import tempfile
 
 sys.path.insert(0, "src")
 
@@ -22,6 +21,7 @@ from repro.serve import (  # noqa: E402
     ChaosMonkey,
     ServeConfig,
     StudyServer,
+    fresh_serve_dir,
     make_storm,
     restart_server,
 )
@@ -47,7 +47,7 @@ def token_demo():
 
 
 def study_service_demo():
-    cache_dir = tempfile.mkdtemp(prefix="repro-serve-demo-")
+    cache_dir = str(fresh_serve_dir("demo"))
     monkey = ChaosMonkey(ChaosConfig(seed=2, fault_rate=0.25, hang_s=5.0))
     cfg = ServeConfig(default_deadline_s=120.0, heartbeat_timeout_s=2.0,
                       backoff_base_s=0.01, max_lanes=64,

@@ -180,9 +180,9 @@ def _lazypim_acc(tt: TraceTensors, hw: HWParams, cfg: LazyPIMConfig):
 
         # --- commit / conflict detection ------------------------------------
         commit = jnp.asarray(True) if cfg.partial_commits else tt.kernel_end[w]
-        # One gather per signature image serves both conflict checks and all
+        # One lookup per signature image serves both conflict checks and all
         # membership masks below.
-        rhits = line_sig_hits(tt, read_bits)    # (n, M)
+        rhits = line_sig_hits(tt, read_bits)    # (M, n)
         c1 = conflict_from_hits(tt, cpuws, rhits, cfg.cpuws_regs) & commit
         exact = jnp.any((cpuws & read_bm) != 0) & commit
 

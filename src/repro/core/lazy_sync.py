@@ -60,6 +60,8 @@ class LazySyncConfig:
     pin_streak: int = 3                # paper's lock-after-3-rollbacks rule
     embed_lr: float = 0.05
     use_kernel: bool = False           # fused Pallas conflict-detect kernel
+    interpret: bool = False            # run that kernel in Pallas interpret
+    #                                    mode (tests off the chip)
 
 
 def init_state(cfg: LazySyncConfig, vocab: int) -> dict:
@@ -174,7 +176,8 @@ class LazyEmbed:
 
             packed = jax.vmap(lambda b: pack_bits(self.spec, b))(sigs)
             hit_groups = bloom_detect_conflicts(
-                self.spec, packed, flat, use_pallas=True
+                self.spec, packed, flat, use_pallas=True,
+                interpret=self.cfg.interpret,
             )
         else:
             if pos is None:

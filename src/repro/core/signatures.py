@@ -214,9 +214,10 @@ def hash_with_tables(
 ) -> jax.Array:
     """Core byte-sliced lookup: (N,) uint32 addrs x (S, 256, M) tables ->
     (N, M) uint32 global positions.  ``tabs`` must be the offset-folded
-    tables from :func:`_h3_tables_global`.  Shared by
-    :func:`hash_positions` and the Pallas kernels
-    (``kernels/bloom/bloom.py``) so the two paths cannot drift."""
+    tables from :func:`_h3_tables_global`.  The Pallas kernels hash with
+    the per-bit xor-fold instead (a table gather does not lower inside a
+    TPU kernel); ``tests/test_bloom_word_kernels.py`` holds the two
+    bit-exact."""
     h = jnp.take(tabs[0], addrs & np.uint32(0xFF), axis=0, mode="clip")
     for k in range(1, spec.num_byte_slices):
         byte = (addrs >> np.uint32(8 * k)) & np.uint32(0xFF)

@@ -7,24 +7,32 @@ signature, and scattered/gathered at *word* granularity.
 
 Design notes (TPU-native, not a port):
 
-* **Byte-sliced H3 in-kernel.**  The hash uses the precomputed lookup tables
-  from :attr:`SignatureSpec.h3_tables` (segment offsets pre-folded, see
-  ``core/signatures.py``): ``num_byte_slices`` table gathers + XORs instead
-  of an ``addr_bits``-round shift/and/select/xor fold.
+* **Layout for the TPU kernel compiler.**  An address block is an
+  ``(BLOCK_N, 1)`` int32 column (addresses down the sublanes), the packed
+  signature a ``(1, num_words)`` int32 row (words across the lanes); uint32
+  data is bitcast to int32 at the kernel boundary.  Every in-kernel value
+  is 2-D, every reduction an int32 sum/max along one axis, and no value is
+  reshaped — the forms the compiler lowers (``tests/test_tpu_compile.py``
+  compiles each kernel for a described v5e chip).
+* **H3 in-kernel as a constant xor-fold.**  The hash matrix is a static
+  function of the spec, so each segment's hash is ``addr_bits`` selects +
+  XORs against compile-time constants: no table operand and no gather,
+  which the kernel compiler cannot lower.  Bit-exact with the byte-sliced
+  XLA path (:func:`repro.core.signatures.hash_positions`).
 * **Word/bit decomposition.**  A global bit position ``pos`` splits into
-  ``word = pos >> 5`` (one of ``num_words`` packed uint32 words, 64 for the
-  paper geometry) and ``bit = 1 << (pos & 31)``.  Insert compares ``word``
-  against a ``num_words``-wide iota — 32x less compare work than the seed
-  kernel's one-hot expand against the full ``sig_bits``-wide iota — then
-  OR-reduces the masked bit contributions down a log2-depth tree.  Query
-  gathers the addressed word (one-hot word-select + sum, exact because the
-  select matrix has exactly one hit per row) and tests the bit mask.  The
-  seed one-hot kernels are kept as ``*_onehot`` for differential tests and
-  the before/after microbench (``benchmarks/bench_signatures.py``).
+  ``word = pos >> 5`` (one of ``num_words`` packed words, 64 for the paper
+  geometry) and ``bit = 1 << (pos & 31)``.  Insert compares ``word``
+  against a ``num_words``-wide lane iota — 32x less compare work than the
+  seed kernel's one-hot expand against the full ``sig_bits``-wide iota —
+  and ORs the masked bits down the sublanes to one 8-row tile, which the
+  wrapper folds.  Query selects the addressed word with the same compare
+  (a sum that is exact: one hit per row) and tests the bit.  The seed
+  one-hot kernels are kept as ``*_onehot`` for differential tests and the
+  before/after microbench (``benchmarks/bench_signatures.py``).
 * The 2 Kbit signature is tiny; the interesting tiling axis is the *address
-  batch*.  ``BlockSpec`` tiles the address stream ``(BLOCK_N,)`` into VMEM and
-  revisits the same whole-signature output block every grid step — the
-  canonical Pallas accumulation pattern (TPU grids execute sequentially, so
+  batch*.  ``BlockSpec`` tiles the address stream into VMEM and revisits
+  the same whole-signature output block every grid step — the canonical
+  Pallas accumulation pattern (TPU grids execute sequentially, so
   read-modify-write on the output ref is safe).
 * ``bloom_detect_conflicts_pallas`` fuses the whole LazySync hot loop —
   hash -> membership across all G group signatures -> per-address hit-group
@@ -45,13 +53,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core.signatures import (
-    SignatureSpec,
-    _h3_tables_global,
-    hash_with_tables,
-)
+from repro.core.signatures import SignatureSpec
 
 DEFAULT_BLOCK_N = 256
+_TILE_ROWS = 8  # sublanes per vreg tile
 
 
 # ---------------------------------------------------------------------------
@@ -59,12 +64,19 @@ DEFAULT_BLOCK_N = 256
 # ---------------------------------------------------------------------------
 
 
-def _h3_hash_block(addrs, tabs, spec: SignatureSpec):
-    """Byte-sliced H3 for a (BLOCK_N,) uint32 address block -> (BLOCK_N, M)
-    int32 global bit positions.  Delegates to the shared
-    :func:`repro.core.signatures.hash_with_tables` so kernel and jnp paths
-    cannot drift."""
-    return hash_with_tables(addrs.astype(jnp.uint32), tabs, spec).astype(jnp.int32)
+def _h3_columns(addrs, spec: SignatureSpec) -> list:
+    """H3 global bit positions of an (R, 1) int32 address column: one (R, 1)
+    int32 column per segment.  Per-bit xor-fold with the hash matrix as
+    compile-time constants and the segment offset added."""
+    q = spec.h3_matrix
+    bits = [((addrs >> j) & 1) != 0 for j in range(spec.addr_bits)]
+    cols = []
+    for m in range(spec.num_segments):
+        h = jnp.zeros_like(addrs)
+        for j, b in enumerate(bits):
+            h = h ^ jnp.where(b, np.int32(q[m, j]), np.int32(0))
+        cols.append(h + np.int32(m * spec.seg_bits))
+    return cols
 
 
 def _h3_hash_block_xorfold(addrs, q, spec: SignatureSpec):
@@ -81,32 +93,46 @@ def _h3_hash_block_xorfold(addrs, q, spec: SignatureSpec):
     return (h + seg_off[None, :]).astype(jnp.int32)
 
 
-def _tree_or(x):
-    """OR-reduce axis 0 of a (R, ...) uint32 array in log2(R) vector steps
-    (Pallas-safe: no lax.reduce with a custom combiner)."""
-    r = x.shape[0]
-    p = 1 << (r - 1).bit_length()
-    if p != r:
-        x = jnp.concatenate(
-            [x, jnp.zeros((p - r,) + x.shape[1:], x.dtype)], axis=0
-        )
-    while x.shape[0] > 1:
-        x = x[0::2] | x[1::2]
-    return x[0]
-
-
 def _word_bit(pos):
-    """Split (.., M) int32 global positions into packed-word index and
-    32-bit lane mask."""
-    word = pos >> 5
-    bit = jnp.left_shift(
-        np.uint32(1), (pos & 31).astype(jnp.uint32)
-    )
-    return word, bit
+    """Split int32 global positions into packed-word index and the int32
+    bit pattern of its 32-bit lane mask."""
+    return pos >> 5, jnp.left_shift(np.int32(1), pos & 31)
 
 
-def _tables_operand(spec: SignatureSpec):
-    return jnp.asarray(_h3_tables_global(spec))
+def _word_lanes(rows: int, spec: SignatureSpec):
+    return jax.lax.broadcasted_iota(jnp.int32, (rows, spec.num_words), 1)
+
+
+def _looked_up(word, lanes, sig_row):
+    """The packed word each row addresses: (R, 1) from a (1, W) row."""
+    return jnp.sum(jnp.where(word == lanes, sig_row, 0), axis=1,
+                   keepdims=True)
+
+
+def _i32(x):
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _u32(x):
+    return jax.lax.bitcast_convert_type(x, jnp.uint32)
+
+
+def _address_column(addrs: jax.Array, block_n: int) -> tuple[jax.Array, int]:
+    """(N,) integer addresses -> ((N_pad, 1) int32 column, N)."""
+    if block_n % _TILE_ROWS:
+        raise ValueError(f"block_n must be a multiple of {_TILE_ROWS}, got "
+                         f"{block_n}")
+    col = _i32(addrs.reshape(-1).astype(jnp.uint32))
+    n = col.shape[0]
+    return jnp.pad(col, (0, (-n) % block_n))[:, None], n
+
+
+def _column_spec(block_n: int):
+    return pl.BlockSpec((block_n, 1), lambda i: (i, 0))
+
+
+def _whole(shape):
+    return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
 
 
 # ---------------------------------------------------------------------------
@@ -114,22 +140,32 @@ def _tables_operand(spec: SignatureSpec):
 # ---------------------------------------------------------------------------
 
 
-def _insert_kernel(
-    addr_ref, mask_ref, tab_ref, out_ref, *, spec: SignatureSpec
-):
+def _or_to_tile(x):
+    """OR-fold the rows of an (R, W) int32 array (R a multiple of 8) down to
+    one (8, W) tile, with tile-aligned contiguous slices only."""
+    rows = x.shape[0]
+    while rows > _TILE_ROWS and rows % (2 * _TILE_ROWS) == 0:
+        rows //= 2
+        x = x[:rows] | x[rows:]
+    out = x[:_TILE_ROWS]
+    for r in range(_TILE_ROWS, rows, _TILE_ROWS):
+        out = out | x[r:r + _TILE_ROWS]
+    return out
+
+
+def _insert_kernel(addr_ref, mask_ref, out_ref, *, spec: SignatureSpec):
     step = pl.program_id(0)
     addrs = addr_ref[...]
-    mask = mask_ref[...]
-    pos = _h3_hash_block(addrs, tab_ref[...], spec)  # (BLK, M)
-    word, bit = _word_bit(pos)
-    word = jnp.where(mask[:, None] > 0, word, -1)
-    # Scatter-as-compare at word granularity: (BLK*M, num_words).
-    tgt = jax.lax.broadcasted_iota(jnp.int32, (word.size, spec.num_words), 1)
-    hit = word.reshape(-1, 1) == tgt
-    contrib = jnp.where(hit, bit.reshape(-1, 1), np.uint32(0))
-    words = _tree_or(contrib)  # (num_words,)
-    prev = jnp.where(step == 0, jnp.zeros_like(words), out_ref[...])
-    out_ref[...] = prev | words
+    live = mask_ref[...] > 0
+    lanes = _word_lanes(addrs.shape[0], spec)
+    contrib = None
+    for pos in _h3_columns(addrs, spec):
+        word, bit = _word_bit(pos)
+        c = jnp.where((word == lanes) & live, bit, 0)  # (BLK, W)
+        contrib = c if contrib is None else contrib | c
+    tile = _or_to_tile(contrib)
+    prev = jnp.where(step == 0, jnp.zeros_like(tile), out_ref[...])
+    out_ref[...] = prev | tile
 
 
 def bloom_insert_pallas(
@@ -139,39 +175,28 @@ def bloom_insert_pallas(
     mask: jax.Array | None = None,
     *,
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Insert a batch of addresses into a packed signature via Pallas.
 
     ``sig``: (num_words,) uint32; ``addrs``: (N,) integer; ``mask`` optional
     (N,) bool.  Returns the updated signature.
     """
-    addrs = addrs.reshape(-1).astype(jnp.uint32)
-    n = addrs.shape[0]
-    if mask is None:
-        mask = jnp.ones((n,), dtype=jnp.int32)
-    else:
-        mask = mask.reshape(-1).astype(jnp.int32)
-    pad = (-n) % block_n
-    if pad:
-        addrs = jnp.pad(addrs, (0, pad))
-        mask = jnp.pad(mask, (0, pad))
-    n_pad = addrs.shape[0]
-    tabs = _tables_operand(spec)
-    grid = (n_pad // block_n,)
-    delta = pl.pallas_call(
+    col, n = _address_column(addrs, block_n)
+    live = (jnp.ones((n,), jnp.int32) if mask is None
+            else mask.reshape(-1).astype(jnp.int32))
+    live = jnp.pad(live, (0, col.shape[0] - n))[:, None]
+    tile_shape = (_TILE_ROWS, spec.num_words)
+    tile = pl.pallas_call(
         functools.partial(_insert_kernel, spec=spec),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec(tabs.shape, lambda i: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((spec.num_words,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((spec.num_words,), jnp.uint32),
+        grid=(col.shape[0] // block_n,),
+        in_specs=[_column_spec(block_n), _column_spec(block_n)],
+        out_specs=_whole(tile_shape),
+        out_shape=jax.ShapeDtypeStruct(tile_shape, jnp.int32),
         interpret=interpret,
-    )(addrs, mask, tabs)
-    return sig | delta
+    )(col, live)
+    delta = jax.lax.reduce(tile, np.int32(0), jax.lax.bitwise_or, (0,))
+    return sig | _u32(delta)
 
 
 # ---------------------------------------------------------------------------
@@ -179,26 +204,15 @@ def bloom_insert_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _query_kernel(
-    addr_ref, tab_ref, sig_ref, out_ref, *, spec: SignatureSpec
-):
+def _query_kernel(addr_ref, sig_ref, out_ref, *, spec: SignatureSpec):
     addrs = addr_ref[...]
-    pos = _h3_hash_block(addrs, tab_ref[...], spec)  # (BLK, M)
-    word, bit = _word_bit(pos)
-    sig = sig_ref[...]  # (num_words,) uint32 packed
-    blk = pos.shape[0]
-    # Word gather as one-hot select + sum (exact: one hit per row).
-    tgt = jax.lax.broadcasted_iota(
-        jnp.int32, (blk * spec.num_segments, spec.num_words), 1
-    )
-    onehot = word.reshape(-1, 1) == tgt
-    looked = jnp.sum(
-        jnp.where(onehot, sig[None, :], np.uint32(0)),
-        axis=1,
-        dtype=jnp.uint32,
-    )  # (BLK*M,)
-    member_seg = (looked & bit.reshape(-1)) != 0
-    member = jnp.all(member_seg.reshape(blk, spec.num_segments), axis=1)
+    sig = sig_ref[...]  # (1, W)
+    lanes = _word_lanes(addrs.shape[0], spec)
+    member = None
+    for pos in _h3_columns(addrs, spec):
+        word, bit = _word_bit(pos)
+        hit = (_looked_up(word, lanes, sig) & bit) != 0
+        member = hit if member is None else member & hit
     out_ref[...] = member.astype(jnp.int32)
 
 
@@ -208,29 +222,20 @@ def bloom_query_pallas(
     addrs: jax.Array,
     *,
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Membership of ``addrs`` (N,) in ``sig`` -> (N,) bool via Pallas."""
-    addrs_flat = addrs.reshape(-1).astype(jnp.uint32)
-    n = addrs_flat.shape[0]
-    pad = (-n) % block_n
-    if pad:
-        addrs_flat = jnp.pad(addrs_flat, (0, pad))
-    n_pad = addrs_flat.shape[0]
-    tabs = _tables_operand(spec)
+    col, n = _address_column(addrs, block_n)
+    row = _i32(sig)[None, :]
     out = pl.pallas_call(
         functools.partial(_query_kernel, spec=spec),
-        grid=(n_pad // block_n,),
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec(tabs.shape, lambda i: (0, 0, 0)),
-            pl.BlockSpec((spec.num_words,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.int32),
+        grid=(col.shape[0] // block_n,),
+        in_specs=[_column_spec(block_n), _whole(row.shape)],
+        out_specs=_column_spec(block_n),
+        out_shape=jax.ShapeDtypeStruct(col.shape, jnp.int32),
         interpret=interpret,
-    )(addrs_flat, tabs, sig)
-    return out[:n].astype(bool)
+    )(col, row)
+    return out[:n, 0].astype(bool)
 
 
 # ---------------------------------------------------------------------------
@@ -238,29 +243,17 @@ def bloom_query_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _conflict_kernel(
-    addr_ref, tab_ref, sigs_ref, out_ref, *, spec: SignatureSpec
-):
+def _conflict_kernel(addr_ref, sigs_ref, out_ref, *, spec: SignatureSpec):
     addrs = addr_ref[...]
-    pos = _h3_hash_block(addrs, tab_ref[...], spec)  # (BLK, M)
-    word, bit = _word_bit(pos)
-    sigs = sigs_ref[...]  # (G, num_words) uint32 packed
-    g = sigs.shape[0]
-    blk = pos.shape[0]
-    tgt = jax.lax.broadcasted_iota(
-        jnp.int32, (blk * spec.num_segments, spec.num_words), 1
-    )
-    onehot = word.reshape(-1, 1) == tgt  # (BLK*M, W)
-    looked = jnp.sum(
-        jnp.where(onehot[None, :, :], sigs[:, None, :], np.uint32(0)),
-        axis=2,
-        dtype=jnp.uint32,
-    )  # (G, BLK*M)
-    member_seg = (looked & bit.reshape(1, -1)) != 0
-    member = jnp.all(
-        member_seg.reshape(g, blk, spec.num_segments), axis=2
-    )  # (G, BLK)
-    out_ref[...] = jnp.sum(member.astype(jnp.int32), axis=0)
+    groups = sigs_ref.shape[0]
+    lanes = _word_lanes(addrs.shape[0], spec)
+    member = [None] * groups
+    for pos in _h3_columns(addrs, spec):
+        word, bit = _word_bit(pos)
+        for g in range(groups):
+            hit = (_looked_up(word, lanes, sigs_ref[g:g + 1, :]) & bit) != 0
+            member[g] = hit if member[g] is None else member[g] & hit
+    out_ref[...] = sum(m.astype(jnp.int32) for m in member)
 
 
 def bloom_detect_conflicts_pallas(
@@ -269,7 +262,7 @@ def bloom_detect_conflicts_pallas(
     addrs: jax.Array,
     *,
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Fused hash -> membership-across-groups -> hit count, one kernel.
 
@@ -277,40 +270,34 @@ def bloom_detect_conflicts_pallas(
     touched ids.  Returns (N,) int32: for each address, the number of group
     signatures that contain it (LazySync flags a conflict when >= 2).
     """
-    addrs_flat = addrs.reshape(-1).astype(jnp.uint32)
-    n = addrs_flat.shape[0]
-    pad = (-n) % block_n
-    if pad:
-        addrs_flat = jnp.pad(addrs_flat, (0, pad))
-    n_pad = addrs_flat.shape[0]
-    tabs = _tables_operand(spec)
+    col, n = _address_column(addrs, block_n)
+    rows = _i32(sigs)
     out = pl.pallas_call(
         functools.partial(_conflict_kernel, spec=spec),
-        grid=(n_pad // block_n,),
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec(tabs.shape, lambda i: (0, 0, 0)),
-            pl.BlockSpec(sigs.shape, lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.int32),
+        grid=(col.shape[0] // block_n,),
+        in_specs=[_column_spec(block_n), _whole(rows.shape)],
+        out_specs=_column_spec(block_n),
+        out_shape=jax.ShapeDtypeStruct(col.shape, jnp.int32),
         interpret=interpret,
-    )(addrs_flat, tabs, sigs)
-    return out[:n]
+    )(col, rows)
+    return out[:n, 0]
 
 
 # ---------------------------------------------------------------------------
-# Batched AND-prefilter (unchanged: already word-level)
+# Batched AND-prefilter (already word-level)
 # ---------------------------------------------------------------------------
 
 
 def _intersect_kernel(a_ref, b_ref, out_ref, *, spec: SignatureSpec):
-    a = a_ref[...]
-    b = b_ref[...]
-    inter = a & b  # (BLK_B, num_words)
-    seg = inter.reshape(a.shape[0], spec.num_segments, spec.words_per_seg)
-    conflict = jnp.all(jnp.any(seg != 0, axis=2), axis=1)
-    out_ref[...] = conflict.astype(jnp.int32)
+    nonzero = (a_ref[...] & b_ref[...]) != 0  # (BLK_B, W)
+    seg = jax.lax.broadcasted_iota(jnp.int32, nonzero.shape, 1) >> (
+        spec.words_per_seg.bit_length() - 1)
+    conflict = None
+    for m in range(spec.num_segments):
+        any_m = jnp.max(jnp.where(nonzero & (seg == m), 1, 0), axis=1,
+                        keepdims=True)
+        conflict = any_m if conflict is None else jnp.minimum(conflict, any_m)
+    out_ref[...] = conflict
 
 
 def bloom_intersect_pallas(
@@ -319,26 +306,21 @@ def bloom_intersect_pallas(
     b: jax.Array,
     *,
     block_b: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Batched AND-prefilter: a, b (B, num_words) -> (B,) bool."""
     bsz = a.shape[0]
     pad = (-bsz) % block_b
-    if pad:
-        a = jnp.pad(a, ((0, pad), (0, 0)))
-        b = jnp.pad(b, ((0, pad), (0, 0)))
+    a, b = (jnp.pad(_i32(x), ((0, pad), (0, 0))) for x in (a, b))
     out = pl.pallas_call(
         functools.partial(_intersect_kernel, spec=spec),
         grid=(a.shape[0] // block_b,),
-        in_specs=[
-            pl.BlockSpec((block_b, spec.num_words), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, spec.num_words), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_b,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((a.shape[0],), jnp.int32),
+        in_specs=[pl.BlockSpec((block_b, spec.num_words), lambda i: (i, 0))] * 2,
+        out_specs=pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((a.shape[0], 1), jnp.int32),
         interpret=interpret,
     )(a, b)
-    return out[:bsz].astype(bool)
+    return out[:bsz, 0].astype(bool)
 
 
 # ---------------------------------------------------------------------------
@@ -371,7 +353,7 @@ def bloom_insert_pallas_onehot(
     mask: jax.Array | None = None,
     *,
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Seed insert kernel: xor-fold hash + full-width one-hot expand."""
     addrs = addrs.reshape(-1).astype(jnp.uint32)
@@ -423,7 +405,7 @@ def bloom_query_pallas_onehot(
     addrs: jax.Array,
     *,
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Seed query kernel: xor-fold hash + one-hot gather over unpacked bits."""
     addrs_flat = addrs.reshape(-1).astype(jnp.uint32)

@@ -83,7 +83,7 @@ def flash_attention_pallas(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool = True, window: int = 0,
     block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """q: (B, Sq, Hq, D); k/v: (B, Sk, Hkv, D) -> (B, Sq, Hq, D)."""
     b, sq, hq, d = q.shape

@@ -1,4 +1,7 @@
-"""Jit'd wrapper: Pallas flash attention on TPU, chunked-jnp oracle on CPU."""
+"""Jit'd wrapper: Pallas flash attention on TPU, chunked-jnp oracle elsewhere.
+
+``interpret`` is the caller's to set; it is never inferred from the
+backend."""
 
 from __future__ import annotations
 
@@ -14,12 +17,13 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "use_pallas"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "use_pallas",
+                                             "interpret"))
 def mha(q, k, v, *, causal: bool = True, window: int = 0,
-        use_pallas: bool | None = None):
+        use_pallas: bool | None = None, interpret: bool = False):
     if use_pallas is None:
         use_pallas = _on_tpu()
     if use_pallas:
         return _pallas.flash_attention_pallas(
-            q, k, v, causal=causal, window=window, interpret=not _on_tpu())
+            q, k, v, causal=causal, window=window, interpret=interpret)
     return _ref.flash_attention_ref(q, k, v, causal=causal, window=window)

@@ -29,7 +29,7 @@ def _merge_kernel(rows_ref, base_ref, valid_ref, out_ref):
 
 def lazy_merge_pallas(rows: jax.Array, base: jax.Array, valid: jax.Array,
                       *, block_r: int = BLOCK_R, block_d: int = BLOCK_D,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool = False) -> jax.Array:
     """rows: (G, R, D); base: (R, D); valid: (R,) -> (R, D) float32."""
     g, r, d = rows.shape
     pr = (-r) % block_r

@@ -21,7 +21,10 @@ LANE_AXIS = "lanes"
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the models place activations with with_sharding_constraint,
+    # which refuses the Explicit axes jax.make_mesh gives by default.
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_lane_mesh(num_devices: int):

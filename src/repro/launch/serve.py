@@ -18,14 +18,18 @@ degradation, crash-safe restart — and print one status line per request::
         --cache-dir .serve_cache [--chaos-rate 0.1] [--deadline-s 300]
 
 With ``--cache-dir`` the server journals admitted requests and keeps the
-persistent compile cache + warm manifest there, so a re-launch answers
-repeat studies without recompiling a single scan.
+warm manifest there; JAX's persistent compile cache sits where
+:func:`repro.runtime.compile_cache.setup_compile_cache` puts it, so a
+re-launch answers repeat studies without recompiling a single scan.  The
+process exits non-zero when any request ends ``failed``, or ``crashed``
+with no restart to answer it.
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import sys
 import time
 
 # Simulated multi-device lane meshes: repro.sim.mesh translates
@@ -40,6 +44,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.models.model import Model
+from repro.runtime.compile_cache import setup_compile_cache
 
 
 @dataclasses.dataclass
@@ -196,8 +201,8 @@ def main():
                     help="serve study requests from this JSON file instead "
                          "of running the token-serving driver")
     ap.add_argument("--cache-dir", default=None,
-                    help="journal + persistent compile cache + warm "
-                         "manifest directory (enables crash-safe restart)")
+                    help="journal + warm manifest directory (enables "
+                         "crash-safe restart)")
     ap.add_argument("--deadline-s", type=float, default=300.0)
     ap.add_argument("--max-queue", type=int, default=64)
     ap.add_argument("--chaos-rate", type=float, default=0.0,
@@ -212,8 +217,14 @@ def main():
                          "slack-driven batch width, repeat-offender group "
                          "keys routed to the sequential reference")
     args = ap.parse_args()
+    setup_compile_cache()
     if args.study:
-        serve_study(args)
+        from repro.serve import CRASHED, FAILED
+
+        bad = [r.rid for r in serve_study(args)
+               if r.status in (FAILED, CRASHED)]
+        if bad:
+            sys.exit(f"requests {bad} ended failed or crashed")
         return
     served = serve(args)
     assert len(served) == args.requests

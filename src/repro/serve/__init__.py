@@ -65,7 +65,7 @@ from repro.serve.server import (
 from repro.serve.warm import (
     ManifestCorruptError,
     WarmCache,
-    enable_persistent_cache,
+    fresh_serve_dir,
 )
 
 __all__ = [
@@ -115,5 +115,5 @@ __all__ = [
     "restart_server",
     "ManifestCorruptError",
     "WarmCache",
-    "enable_persistent_cache",
+    "fresh_serve_dir",
 ]

@@ -1,14 +1,15 @@
 """Crash-safe warm-compile recovery: persistent XLA cache + warm manifest.
 
 The compile budget is planned and gated (``Study.plan`` == measured
-``sweep_cache_sizes`` deltas), but a fresh process still pays it — 96 s
-cold vs 10.4 s warm for the fig7 fleet.  This module makes the budget a
-*per-machine* cost instead of a per-process one, in two layers:
+``sweep_cache_sizes`` deltas), but a fresh process still pays it.  Two
+layers make the budget a *per-machine* cost instead of a per-process one:
 
-1. **Persistent XLA compilation cache** — :func:`enable_persistent_cache`
-   points JAX's on-disk compilation cache at the server's cache directory,
-   so any re-trace of a known (geometry, spec, static-flag) scan
-   deserializes the compiled executable instead of re-running XLA.
+1. **Persistent XLA compilation cache** — the entry point places it
+   (:func:`repro.runtime.compile_cache.setup_compile_cache`: the directory
+   ``JAX_COMPILATION_CACHE_DIR`` names, else one fixed path in the
+   checkout), so any re-trace of a known (geometry, spec, static-flag)
+   scan deserializes the compiled executable instead of re-running XLA.
+   The server's ``cache_dir`` holds only its journal and warm manifest.
 
 2. **Warm manifest** — the compiled-scan *key space* is exactly the
    planner's (mechanism, bucket geometry, lane count, signature spec,
@@ -36,11 +37,14 @@ from __future__ import annotations
 import dataclasses
 import json
 import pathlib
+import shutil
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.coherence import LazyPIMConfig
 from repro.core.signatures import SignatureSpec
+from repro.runtime.compile_cache import CHECKOUT
 from repro.sim import engine as _engine
 from repro.sim import mesh as _mesh
 from repro.sim.costmodel import HWParams
@@ -70,20 +74,14 @@ class ManifestCorruptError(ValueError):
     write must cost the warm state, never wedge ``restart_server``."""
 
 
-def enable_persistent_cache(cache_dir: str | pathlib.Path) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (min-size /
-    min-compile-time thresholds dropped so every scan qualifies).  Returns
-    False — without raising — on JAX versions that lack the flags; the warm
-    manifest still works, the replay just pays real XLA compiles."""
-    cache_dir = pathlib.Path(cache_dir)
-    cache_dir.mkdir(parents=True, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        return True
-    except (AttributeError, ValueError):
-        return False
+def fresh_serve_dir(name: str) -> pathlib.Path:
+    """An emptied server ``cache_dir`` at a fixed path in the checkout
+    (``.serve_cache/<name>``), for runs that must start with no journal to
+    replay and no manifest to warm: the demos and the serve benchmark."""
+    path = CHECKOUT / ".serve_cache" / name
+    shutil.rmtree(path, ignore_errors=True)
+    path.mkdir(parents=True)
+    return path
 
 
 def study_warm_entries(study: Study, devices: int = 1) -> list[dict]:
@@ -124,11 +122,13 @@ def _entry_key(e: dict) -> str:
 def dummy_stacked(entry: dict):
     """Build the (stacked trace, stacked hw, stacked lazy) triple whose jit
     key equals a manifest entry's compile key: exact bucket geometry and
-    lane count, every lane the all-sentinel :func:`dummy_trace`."""
+    lane count, every lane the all-sentinel :func:`dummy_trace`.  Built
+    from jnp ops only, so ``jax.eval_shape`` gives the entry's argument
+    shapes without materializing any lane (``tests/test_tpu_compile.py``)."""
     tt = dummy_trace(SignatureSpec(**entry["spec"]),
                      **{k: entry[k] for k in _GEOMETRY_KEYS})
     lanes = entry["lanes"]
-    stt = _engine.stack_traces([tt] * lanes)
+    stt = jax.tree.map(lambda x: jnp.broadcast_to(x, (lanes, *x.shape)), tt)
     shw = _engine.stack_hw([HWParams()] * lanes)
     scfg = _engine.stack_lazy(
         [LazyPIMConfig(**entry["lazy_static"])] * lanes)
@@ -142,7 +142,6 @@ class WarmCache:
         self.dir = pathlib.Path(cache_dir)
         self.dir.mkdir(parents=True, exist_ok=True)
         self.manifest_path = self.dir / MANIFEST_NAME
-        self.persistent = enable_persistent_cache(self.dir)
         self.quarantined_manifests = 0  # corrupt files set aside, not read
         self.skipped_entries = 0        # mesh entries this host cannot replay
 

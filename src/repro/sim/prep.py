@@ -26,12 +26,12 @@ algebra (OR/AND/select/popcount) runs word-wise:
 * ``bank_bits_from_bitmap``— packed CPUWriteSet register bank
 * ``conflict_any``         — paper §5.3 AND-prefilter over segment-aligned
                              word masks
-* ``line_sig_hits``        — per-(line, segment) signature bit lookups; the
-                             shared gather behind ``members`` and
+* ``line_sig_hits``        — per-(segment, line) signature bit lookups; the
+                             shared lookup behind ``members`` and
                              ``conflict_from_hits``
 * ``members``              — packed membership mask (with real H3 FPs)
 * ``conflict_from_hits``   — ``conflict_any∘bank_bits_from_bitmap`` fused
-                             into a gather + mod-``R`` segment reduction
+                             into a lookup + mod-``R`` segment reduction
                              (no scatter); bit-exact with the unfused pair
 * ``evict_to_cap``         — capacity eviction via word popcounts
 * ``cpu_cache_step``       — CPU-side presence/dirty word-bitmap evolution
@@ -126,7 +126,7 @@ class TraceTensors:
     spec: SignatureSpec
 
     # Per-line static tables
-    line_pos: jax.Array      # (num_lines, M) int32 global signature bit positions
+    line_pos: jax.Array      # (M, num_lines) int32 global signature bit positions
     line_reg: jax.Array      # (num_lines,) int32 CPUWriteSet register id
 
     # Access lists (−1 = empty slot) + validity masks
@@ -260,12 +260,19 @@ def gather_hits(words: jax.Array, ids: jax.Array, valid: jax.Array) -> jax.Array
 # ---------------------------------------------------------------------------
 
 
+def _ids_pos(tt: TraceTensors, ids: jax.Array) -> jax.Array:
+    """(M, A) signature positions of the line ids (A,): one 1-D gather per
+    segment row, so the compiler never re-lays the (M, num_lines) table
+    out with its short segment axis minor (32x padded on the TPU)."""
+    idx = jnp.clip(ids, 0, tt.num_lines - 1)
+    return jnp.stack([tt.line_pos[m][idx] for m in range(tt.num_segments)])
+
+
 def sig_bits_from_ids(
     tt: TraceTensors, ids: jax.Array, valid: jax.Array
 ) -> jax.Array:
     """Packed Bloom image (sig_words,) uint32 of the valid line ids (A,)."""
-    pos = tt.line_pos[jnp.clip(ids, 0, tt.num_lines - 1)]  # (A, M)
-    pos = jnp.where(valid[:, None], pos, tt.sig_bits)
+    pos = jnp.where(valid[None, :], _ids_pos(tt, ids), tt.sig_bits)
     return scatter_set(jnp.zeros((tt.sig_words,), jnp.uint32),
                        pos.reshape(-1), None, tt.sig_bits)
 
@@ -302,26 +309,37 @@ def conflict_any(tt: TraceTensors, read_words: jax.Array, bank_words: jax.Array)
 
 
 def line_sig_hits(tt: TraceTensors, sig_words: jax.Array) -> jax.Array:
-    """Per-(line, segment) signature bit lookups -> (num_lines, M) bool.
+    """Per-(segment, line) signature bit lookups -> (M, num_lines) bool.
 
-    One gather from the packed image serves every consumer in a simulator
+    One lookup of the packed image serves every consumer in a simulator
     step: ``members`` is the all-segments AND, ``conflict_from_hits`` the
-    per-register segment OR — so the packed LazyPIM step gathers each image
-    once instead of once per membership/bank call."""
-    pos = tt.line_pos  # (n, M) int32 global positions, segment m in column m
-    w = sig_words[pos >> 5]
+    per-register segment OR — so the packed LazyPIM step looks each image
+    up once instead of once per membership/bank call.
+
+    Lines run along the minor axis and the word lookup is a select chain
+    over the segment's ``words_per_seg`` words, not a gather: row ``m`` of
+    ``line_pos`` lies in segment ``m``.  A ``(num_lines, M)`` gather pads
+    its minor axis of 4 to the TPU's 128 lanes (32x the bytes) and runs
+    element by element."""
+    pos = tt.line_pos  # (M, n) int32 global positions, segment m in row m
+    wps = tt.spec.words_per_seg
+    seg_words = sig_words.reshape(tt.num_segments, wps)
+    local = (pos >> 5) & (wps - 1)  # word within the segment (wps is pow2)
+    w = jnp.zeros(pos.shape, jnp.uint32)
+    for j in range(wps):
+        w = jnp.where(local == j, seg_words[:, j:j + 1], w)
     return ((w >> (pos & 31).astype(jnp.uint32)) & 1) != 0
 
 
 def members(tt: TraceTensors, words: jax.Array, sig_words: jax.Array) -> jax.Array:
     """Packed per-line signature membership mask for lines set in ``words``.
     Includes the signature's real false positives."""
-    return words & pack_bitmap(jnp.all(line_sig_hits(tt, sig_words), axis=1))
+    return members_from_hits(words, line_sig_hits(tt, sig_words))
 
 
 def members_from_hits(words: jax.Array, hits: jax.Array) -> jax.Array:
-    """``members`` given a precomputed :func:`line_sig_hits` gather."""
-    return words & pack_bitmap(jnp.all(hits, axis=1))
+    """``members`` given a precomputed :func:`line_sig_hits` lookup."""
+    return words & pack_bitmap(jnp.all(hits, axis=0))
 
 
 def conflict_from_hits(
@@ -335,24 +353,28 @@ def conflict_from_hits(
     the read image is non-empty iff some line ``i ≡ r (mod num_regs)`` set
     in ``words`` has its segment-``m`` hash bit set in the image — each
     line's M positions land in M distinct segments, so the bank scatter
-    collapses to a gather (``hits``) plus a mod-``num_regs`` any-reduction.
+    collapses to a lookup (``hits``) plus a mod-``num_regs`` any-reduction.
+    The reduction first folds rows of ``group`` lines (a multiple of
+    ``num_regs`` near the TPU's 128 lanes), so no axis as narrow as
+    ``num_regs`` is ever the minor one of a num_lines-sized array.
     Bit-exact with the unfused pair (differentially tested)."""
     n = tt.num_lines
-    masked = hits & unpack_bitmap(words, n)[:, None]
-    pad = (-n) % num_regs
-    masked = jnp.pad(masked, ((0, pad), (0, 0)))
-    seg_any = jnp.any(masked.reshape(-1, num_regs, tt.num_segments), axis=0)
-    return jnp.any(jnp.all(seg_any, axis=1))
+    masked = hits & unpack_bitmap(words, n)[None, :]  # (M, n)
+    group = num_regs * max(1, 128 // num_regs)
+    masked = jnp.pad(masked, ((0, 0), (0, (-n) % group)))
+    rows = jnp.any(masked.reshape(tt.num_segments, -1, group), axis=1)
+    seg_any = jnp.any(rows.reshape(tt.num_segments, -1, num_regs), axis=1)
+    return jnp.any(jnp.all(seg_any, axis=0))
 
 
 def ids_member(
     tt: TraceTensors, ids: jax.Array, valid: jax.Array, sig_words: jax.Array
 ) -> jax.Array:
     """Signature membership for an address list (A,) -> (A,) bool."""
-    pos = tt.line_pos[jnp.clip(ids, 0, tt.num_lines - 1)]
+    pos = _ids_pos(tt, ids)
     w = sig_words[pos >> 5]
     hit = ((w >> (pos & 31).astype(jnp.uint32)) & 1) != 0
-    return valid & jnp.all(hit, axis=1)
+    return valid & jnp.all(hit, axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -445,7 +467,7 @@ def cpu_cache_step(
 
 
 def _sig_image_bool(tt: TraceTensors, bitmap: jax.Array) -> jax.Array:
-    pos = jnp.where(bitmap[:, None], tt.line_pos, tt.sig_bits)  # (n, M)
+    pos = jnp.where(bitmap[None, :], tt.line_pos, tt.sig_bits)  # (M, n)
     staged = jnp.zeros((tt.sig_bits + 1,), dtype=bool)
     staged = staged.at[pos.reshape(-1)].set(True, mode="drop")
     return staged[: tt.sig_bits]
@@ -455,8 +477,8 @@ def _bank_image_bool(
     tt: TraceTensors, bitmap: jax.Array, num_regs: int
 ) -> jax.Array:
     stride = tt.sig_bits + 1
-    pos = jnp.where(bitmap[:, None], tt.line_pos, tt.sig_bits)  # (n, M)
-    flat = tt.line_reg[:, None] * stride + pos  # (n, M)
+    pos = jnp.where(bitmap[None, :], tt.line_pos, tt.sig_bits)  # (M, n)
+    flat = tt.line_reg[None, :] * stride + pos  # (M, n)
     staged = jnp.zeros((num_regs * stride,), dtype=bool)
     staged = staged.at[flat.reshape(-1)].set(True, mode="drop")
     return staged.reshape(num_regs, stride)[:, : tt.sig_bits]
@@ -466,8 +488,8 @@ def sig_bits_from_ids_bool(
     tt: TraceTensors, ids: jax.Array, valid: jax.Array
 ) -> jax.Array:
     """Bloom image (sig_bits,) bool of the valid line ids in ``ids`` (A,)."""
-    pos = tt.line_pos[jnp.clip(ids, 0, tt.num_lines - 1)]  # (A, M)
-    pos = jnp.where(valid[:, None], pos, tt.sig_bits)
+    pos = tt.line_pos[:, jnp.clip(ids, 0, tt.num_lines - 1)]  # (M, A)
+    pos = jnp.where(valid[None, :], pos, tt.sig_bits)
     staged = jnp.zeros((tt.sig_bits + 1,), dtype=bool)
     staged = staged.at[pos.reshape(-1)].set(True, mode="drop")
     return staged[: tt.sig_bits]
@@ -496,16 +518,16 @@ def conflict_any_bool(
 
 def members_bool(tt: TraceTensors, bitmap: jax.Array, bits: jax.Array) -> jax.Array:
     """Per-line signature membership (n,) bool for lines set in ``bitmap``."""
-    looked = bits[tt.line_pos]  # (n, M)
-    return bitmap & jnp.all(looked, axis=1)
+    looked = bits[tt.line_pos]  # (M, n)
+    return bitmap & jnp.all(looked, axis=0)
 
 
 def ids_member_bool(
     tt: TraceTensors, ids: jax.Array, valid: jax.Array, bits: jax.Array
 ) -> jax.Array:
     """Signature membership for an address list against a boolean image."""
-    pos = tt.line_pos[jnp.clip(ids, 0, tt.num_lines - 1)]
-    return valid & jnp.all(bits[pos], axis=1)
+    pos = tt.line_pos[:, jnp.clip(ids, 0, tt.num_lines - 1)]  # (M, A)
+    return valid & jnp.all(bits[pos], axis=0)
 
 
 def scatter_set_bool(bitmap: jax.Array, ids: jax.Array, valid: jax.Array) -> jax.Array:
@@ -633,6 +655,14 @@ def _pack_rows_np(bits: np.ndarray) -> np.ndarray:
     return (b << np.arange(32, dtype=np.uint32)).sum(-1, dtype=np.uint64).astype(np.uint32)
 
 
+def line_positions(spec: SignatureSpec, start: int, stop: int) -> jax.Array:
+    """H3 positions of lines ``start..stop-1`` as the (M, stop - start)
+    int32 ``line_pos`` table: lines along the minor axis, so the per-line
+    work of a simulator step is lane-dense on the TPU."""
+    ids = jnp.arange(start, stop, dtype=jnp.uint32)
+    return hash_positions(spec, ids).astype(jnp.int32).T
+
+
 def prepare(trace: WindowTrace, spec: SignatureSpec | None = None) -> TraceTensors:
     """Stage a WindowTrace onto device with precomputed hash tables.
 
@@ -654,8 +684,7 @@ def prepare(trace: WindowTrace, spec: SignatureSpec | None = None) -> TraceTenso
     pre_writes = np.asarray(trace.pre_writes)
     # Byte-sliced H3 positions for every line in the PIM data region
     # (one-time; hash_positions is the fast table-lookup path).
-    line_ids = jnp.arange(n, dtype=jnp.uint32)
-    line_pos = hash_positions(spec, line_ids).astype(jnp.int32)  # (n, M)
+    line_pos = line_positions(spec, 0, n)
     line_reg = (jnp.arange(n, dtype=jnp.int32)) % CPUWS_REGS
 
     def dev(x, dt=jnp.int32):
@@ -732,8 +761,7 @@ def dummy_trace(spec: SignatureSpec, *, num_lines: int, num_windows: int,
     return TraceTensors(
         name="", threads=0,  # pre-neutralized: same key as neutral_trace
         num_lines=n, num_windows=w, num_kernels=k, spec=spec,
-        line_pos=hash_positions(
-            spec, jnp.arange(n, dtype=jnp.uint32)).astype(jnp.int32),
+        line_pos=line_positions(spec, 0, n),
         line_reg=jnp.arange(n, dtype=jnp.int32) % CPUWS_REGS,
         pim_reads=slots(pim_read_slots),
         pim_writes=slots(pim_write_slots),
@@ -842,9 +870,8 @@ def pad_trace(
     fields.update(num_lines=n2, num_windows=w2, num_kernels=k2)
 
     if n2 > n:
-        extra_ids = jnp.arange(n, n2, dtype=jnp.uint32)
         fields["line_pos"] = jnp.concatenate(
-            [tt.line_pos, hash_positions(tt.spec, extra_ids).astype(jnp.int32)])
+            [tt.line_pos, line_positions(tt.spec, n, n2)], axis=1)
         fields["line_reg"] = jnp.arange(n2, dtype=jnp.int32) % CPUWS_REGS
 
     valid_of = {"pim_reads": "pim_r_valid", "pim_writes": "pim_w_valid",

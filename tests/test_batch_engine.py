@@ -27,6 +27,7 @@ from repro.sim import prep as P
 from repro.sim.costmodel import HWParams
 from repro.sim.engine import (
     MECHANISMS,
+    lanes_per_step,
     run_all,
     run_batch,
     stack_traces,
@@ -196,6 +197,20 @@ def test_padded_line_tables_match_native_prepare(small_pair):
     padded = pad_trace(tt, num_lines=bound)
     want = hash_positions(tt.spec, jnp.arange(bound, dtype=jnp.uint32))
     np.testing.assert_array_equal(np.asarray(padded.line_pos),
-                                  np.asarray(want.astype(jnp.int32)))
+                                  np.asarray(want.astype(jnp.int32)).T)
     np.testing.assert_array_equal(np.asarray(padded.line_reg),
                                   np.arange(bound) % P.CPUWS_REGS)
+
+
+@pytest.mark.parametrize("lanes,num_lines,want", [
+    (8, 1 << 20, 1),    # the large bucket: one lane per scan step
+    (5, 1 << 18, 1),
+    (11, 1 << 16, 1),   # cap 4, but 11 is prime: equal groups only
+    (8, 1 << 16, 4),
+    (6, 1 << 14, 6),    # every lane fits one step: a plain vmap
+])
+def test_lanes_per_step(lanes, num_lines, want):
+    """The fleet differential above takes the grouped path (its 64 Ki- and
+    256 Ki-line buckets run one lane per step) and the vmapped one (16 Ki)
+    and holds both bit-exact with sequential runs."""
+    assert lanes_per_step(lanes, num_lines) == want

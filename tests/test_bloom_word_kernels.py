@@ -157,7 +157,8 @@ def test_ops_detect_conflicts_wrapper():
     ])
     probes = _addrs(128, seed=0)
     ref_counts = ops.bloom_detect_conflicts(spec, sigs, probes, use_pallas=False)
-    knl_counts = ops.bloom_detect_conflicts(spec, sigs, probes, use_pallas=True)
+    knl_counts = ops.bloom_detect_conflicts(spec, sigs, probes, use_pallas=True,
+                                            interpret=True)
     np.testing.assert_array_equal(np.asarray(ref_counts), np.asarray(knl_counts))
     # every group's own addresses must be counted (no false negatives)
     own = ops.bloom_detect_conflicts(spec, sigs, _addrs(80, seed=0))

@@ -101,6 +101,7 @@ def test_ops_wrappers_dispatch_to_ref_on_cpu():
 def test_ops_pallas_path_cpu_interpret():
     spec = SPECS["paper_2k_m4"]
     addrs = _addrs(64, 9)
-    sig = ops.bloom_insert(spec, S.empty_signature(spec), addrs, use_pallas=True)
+    sig = ops.bloom_insert(spec, S.empty_signature(spec), addrs, use_pallas=True,
+                           interpret=True)
     want = ops.bloom_insert(spec, S.empty_signature(spec), addrs, use_pallas=False)
     np.testing.assert_array_equal(np.asarray(sig), np.asarray(want))

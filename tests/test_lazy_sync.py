@@ -242,7 +242,7 @@ def test_fused_kernel_conflict_path_matches(setup):
     bit-identical to the jnp path."""
     import dataclasses as dc
     mcfg, cfg, emb, params, state = setup
-    emb_k = LazyEmbed(mcfg, dc.replace(cfg, use_kernel=True))
+    emb_k = LazyEmbed(mcfg, dc.replace(cfg, use_kernel=True, interpret=True))
     touched, grads = _rand_touch_grads(mcfg, cfg, jax.random.key(9))
     sigs = emb.signatures(touched)
     rows, valid = emb.detect_conflicts(touched, sigs)

@@ -9,6 +9,7 @@ process keeps its single-device view.
 from __future__ import annotations
 
 import os
+import pathlib
 import subprocess
 import sys
 
@@ -23,14 +24,13 @@ import pytest
 _TIMEOUT_S = float(os.environ.get("REPRO_MOE_EP_TIMEOUT_S", 420))
 
 _SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
 from repro.models.model import Model
 from repro.models import common as C
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg0 = get_smoke_config('qwen2_moe_a2_7b')
 cfg0 = dataclasses.replace(cfg0, moe=dataclasses.replace(cfg0.moe, capacity_factor=8.0))
 tokens = jax.random.randint(jax.random.key(1), (4, 16), 0, cfg0.vocab_size)
@@ -54,13 +54,21 @@ print("EP_OK")
 """
 
 
-@pytest.mark.slow
+_ROOT = pathlib.Path(__file__).resolve().parent.parent
+
+
 def test_ep_matches_sort_on_mesh():
+    # The child keeps the parent's environment (JAX_PLATFORMS among it) and
+    # only gains its 8 forced host devices and the source path.
+    env = {**os.environ,
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+           "PYTHONPATH": os.pathsep.join(
+               filter(None, [str(_ROOT / "src"),
+                             os.environ.get("PYTHONPATH")]))}
     try:
         out = subprocess.run(
             [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,
-            env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}, cwd=".",
-            timeout=_TIMEOUT_S)
+            env=env, cwd=_ROOT, timeout=_TIMEOUT_S)
     except subprocess.TimeoutExpired:
         pytest.skip(
             f"moe EP subprocess exceeded {_TIMEOUT_S:.0f}s on this backend "
