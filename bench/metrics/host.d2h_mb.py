@@ -1,0 +1,15 @@
+"""Transfers: megabytes per study read from the device to the host, summed
+over every program span's ``d2h_bytes``."""
+
+import importlib.util
+import pathlib
+
+_spec = importlib.util.spec_from_file_location(
+    "bench_metric_program_common",
+    pathlib.Path(__file__).with_name("_program.py"))
+_program = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_program)
+
+
+def read(run):
+    return _program.megabytes(run, "d2h_bytes")

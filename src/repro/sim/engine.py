@@ -62,6 +62,7 @@ from repro.core.mechanisms import (
     simulate_nc,
 )
 from repro.core.signatures import SignatureSpec
+from repro.runtime import spans
 from repro.sim.costmodel import HWParams, hw_leaf_dtypes
 from repro.sim.prep import (
     TRACE_DATA_FIELDS,
@@ -119,7 +120,7 @@ def stack_hw(hws: list[HWParams]) -> HWParams:
     dtypes = hw_leaf_dtypes()
     kw = {}
     for f in dataclasses.fields(HWParams):
-        kw[f.name] = jnp.asarray(np.asarray(
+        kw[f.name] = spans.h2d(np.asarray(
             [getattr(h, f.name) for h in hws],
             dtype=np.dtype(dtypes[f.name])))
     return HWParams(**kw)
@@ -153,7 +154,7 @@ def stack_lazy(cfgs: list[LazyPIMConfig]) -> LazyPIMConfig:
                     f"stacked sweep")
     kw = {f: getattr(c0, f) for f in _LAZY_STATIC_FIELDS}
     for name, dt in _LAZY_DATA_DTYPES.items():
-        kw[name] = jnp.asarray(np.asarray(
+        kw[name] = spans.h2d(np.asarray(
             [getattr(c, name) for c in cfgs], dtype=np.dtype(dt)))
     return LazyPIMConfig(**kw)
 
@@ -187,8 +188,8 @@ def stack_traces(tts: list[TraceTensors]) -> TraceTensors:
         # Host-side stack + one device put per field: jnp.stack on a list
         # of device arrays issues expand_dims+concatenate per *element*,
         # whose dispatch overhead dominates wide (coalesced) stacks.
-        fields[key] = jnp.asarray(
-            np.stack([np.asarray(getattr(t, key)) for t in tts]))
+        fields[key] = spans.h2d(
+            np.stack([spans.d2h(getattr(t, key)) for t in tts]))
     return TraceTensors(**fields)
 
 
@@ -328,14 +329,20 @@ def _sweep_accs(
     a ``devices``-wide lane mesh (the lane count must already be a multiple
     of ``devices`` — the planner pads with :func:`repro.sim.prep.dummy_trace`
     lanes).  ``devices=1`` is the byte-identical single-device path.
+
+    Each dispatch is a ``repro:scan:<mechanism>`` span in a profiler trace
+    (the call and the read of its accumulators, ``d2h_bytes``, ``lanes``);
+    the compiled program itself stays ``jit_run``.
     """
     out = {}
+    lanes = int(stt.window_valid.shape[0])
     for m in mechanisms:
         fn = _sweep_fn_mesh(m, devices)
 
         def thunk(m=m, fn=fn):
-            acc = fn(stt, shw, scfg) if m == "lazypim" else fn(stt, shw)
-            return {k: jax.device_get(v) for k, v in acc.items()}
+            with spans.span("scan:" + m, lanes=lanes):
+                acc = fn(stt, shw, scfg) if m == "lazypim" else fn(stt, shw)
+                return {k: spans.d2h(v) for k, v in acc.items()}
 
         out[m] = thunk() if boundary is None else boundary(m, thunk)
     return out

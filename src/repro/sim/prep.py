@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.signatures import SignatureSpec, default_spec, hash_positions
+from repro.runtime import spans
 from repro.sim.costmodel import HWParams, LINE_BYTES
 from repro.sim.trace import WindowTrace
 
@@ -674,54 +675,65 @@ def prepare(trace: WindowTrace, spec: SignatureSpec | None = None) -> TraceTenso
 
     Uses the shared :func:`default_spec` singleton when no spec is given so
     the byte-sliced H3 tables (and every jit cache keyed on the spec, which
-    is static TraceTensors metadata) are reused across traces."""
+    is static TraceTensors metadata) are reused across traces.
+
+    In a profiler trace this is the ``repro:prepare`` span (its first host
+    read waits for the synthesis program to finish), with the host numpy
+    packing and unique counts in ``repro:pack``."""
     spec = spec or default_spec()
     n = trace.num_lines
-    pim_reads = np.asarray(trace.pim_reads)
-    pim_writes = np.asarray(trace.pim_writes)
-    cpu_reads = np.asarray(trace.cpu_reads)
-    cpu_writes = np.asarray(trace.cpu_writes)
-    pre_writes = np.asarray(trace.pre_writes)
-    # Byte-sliced H3 positions for every line in the PIM data region
-    # (one-time; hash_positions is the fast table-lookup path).
-    line_pos = line_positions(spec, 0, n)
-    line_reg = (jnp.arange(n, dtype=jnp.int32)) % CPUWS_REGS
+    with spans.span("prepare"):
+        pim_reads = spans.d2h(trace.pim_reads)
+        pim_writes = spans.d2h(trace.pim_writes)
+        cpu_reads = spans.d2h(trace.cpu_reads)
+        cpu_writes = spans.d2h(trace.cpu_writes)
+        pre_writes = spans.d2h(trace.pre_writes)
+        with spans.span("pack"):
+            pre_writes_words = _pack_rows_np(pre_writes)
+            uniq_r = _uniq_count(pim_reads)
+            uniq_w = _uniq_count(pim_writes)
+            uniq = _uniq_union_count(pim_reads, pim_writes)
+        # Byte-sliced H3 positions for every line in the PIM data region
+        # (one-time; hash_positions is the fast table-lookup path).
+        line_pos = line_positions(spec, 0, n)
+        line_reg = (jnp.arange(n, dtype=jnp.int32)) % CPUWS_REGS
 
-    def dev(x, dt=jnp.int32):
-        return jnp.asarray(x, dtype=dt)
+        def dev(x, dt=jnp.int32):
+            return spans.h2d(x, dt)
 
-    return TraceTensors(
-        name=trace.name,
-        threads=trace.threads,
-        num_lines=n,
-        num_windows=trace.num_windows,
-        num_kernels=trace.num_kernels,
-        spec=spec,
-        line_pos=line_pos,
-        line_reg=line_reg,
-        pim_reads=dev(pim_reads),
-        pim_writes=dev(pim_writes),
-        cpu_reads=dev(cpu_reads),
-        cpu_writes=dev(cpu_writes),
-        pim_r_valid=dev(pim_reads >= 0, jnp.bool_),
-        pim_w_valid=dev(pim_writes >= 0, jnp.bool_),
-        cpu_r_valid=dev(cpu_reads >= 0, jnp.bool_),
-        cpu_w_valid=dev(cpu_writes >= 0, jnp.bool_),
-        kernel_id=dev(trace.kernel_id),
-        kernel_start=dev(trace.kernel_start, jnp.bool_),
-        kernel_end=dev(trace.kernel_end, jnp.bool_),
-        pre_writes=dev(pre_writes, jnp.bool_),
-        pre_writes_words=dev(_pack_rows_np(pre_writes), jnp.uint32),
-        pim_instr=dev(trace.pim_instr, jnp.float32),
-        cpu_instr=dev(trace.cpu_instr, jnp.float32),
-        cpu_priv=dev(trace.cpu_priv_accesses, jnp.float32),
-        cpu_priv_miss_rate=dev(float(trace.cpu_priv_miss_rate), jnp.float32),
-        cpu_reuse=dev(float(trace.cpu_reuse), jnp.float32),
-        pim_uniq_r=dev(_uniq_count(pim_reads), jnp.float32),
-        pim_uniq_w=dev(_uniq_count(pim_writes), jnp.float32),
-        pim_uniq=dev(_uniq_union_count(pim_reads, pim_writes), jnp.float32),
-        window_valid=jnp.ones((trace.num_windows,), dtype=jnp.bool_),
-    )
+        return TraceTensors(
+            name=trace.name,
+            threads=trace.threads,
+            num_lines=n,
+            num_windows=trace.num_windows,
+            num_kernels=trace.num_kernels,
+            spec=spec,
+            line_pos=line_pos,
+            line_reg=line_reg,
+            pim_reads=dev(pim_reads),
+            pim_writes=dev(pim_writes),
+            cpu_reads=dev(cpu_reads),
+            cpu_writes=dev(cpu_writes),
+            pim_r_valid=dev(pim_reads >= 0, jnp.bool_),
+            pim_w_valid=dev(pim_writes >= 0, jnp.bool_),
+            cpu_r_valid=dev(cpu_reads >= 0, jnp.bool_),
+            cpu_w_valid=dev(cpu_writes >= 0, jnp.bool_),
+            kernel_id=dev(trace.kernel_id),
+            kernel_start=dev(trace.kernel_start, jnp.bool_),
+            kernel_end=dev(trace.kernel_end, jnp.bool_),
+            pre_writes=dev(pre_writes, jnp.bool_),
+            pre_writes_words=dev(pre_writes_words, jnp.uint32),
+            pim_instr=dev(trace.pim_instr, jnp.float32),
+            cpu_instr=dev(trace.cpu_instr, jnp.float32),
+            cpu_priv=dev(trace.cpu_priv_accesses, jnp.float32),
+            cpu_priv_miss_rate=dev(float(trace.cpu_priv_miss_rate),
+                                   jnp.float32),
+            cpu_reuse=dev(float(trace.cpu_reuse), jnp.float32),
+            pim_uniq_r=dev(uniq_r, jnp.float32),
+            pim_uniq_w=dev(uniq_w, jnp.float32),
+            pim_uniq=dev(uniq, jnp.float32),
+            window_valid=jnp.ones((trace.num_windows,), dtype=jnp.bool_),
+        )
 
 
 def neutral_trace(tt: TraceTensors) -> TraceTensors:
@@ -854,51 +866,54 @@ def pad_trace(
     geometry whose extra lines are simply never touched.  Differentially
     tested bit-exact against the unpadded path on every ``SimResult`` field.
     """
-    n, n2 = tt.num_lines, num_lines or tt.num_lines
-    w, w2 = tt.num_windows, num_windows or tt.num_windows
-    k, k2 = tt.num_kernels, num_kernels or tt.num_kernels
-    widths = {
-        "pim_reads": pim_read_slots, "pim_writes": pim_write_slots,
-        "cpu_reads": cpu_read_slots, "cpu_writes": cpu_write_slots,
-    }
-    for label, cur, tgt in (("num_lines", n, n2), ("num_windows", w, w2),
-                            ("num_kernels", k, k2)):
-        if tgt < cur:
-            raise ValueError(f"cannot shrink {label}: {cur} -> {tgt}")
+    with spans.span("pad"):
+        n, n2 = tt.num_lines, num_lines or tt.num_lines
+        w, w2 = tt.num_windows, num_windows or tt.num_windows
+        k, k2 = tt.num_kernels, num_kernels or tt.num_kernels
+        widths = {
+            "pim_reads": pim_read_slots, "pim_writes": pim_write_slots,
+            "cpu_reads": cpu_read_slots, "cpu_writes": cpu_write_slots,
+        }
+        for label, cur, tgt in (("num_lines", n, n2), ("num_windows", w, w2),
+                                ("num_kernels", k, k2)):
+            if tgt < cur:
+                raise ValueError(f"cannot shrink {label}: {cur} -> {tgt}")
 
-    fields = {f.name: getattr(tt, f.name) for f in dataclasses.fields(tt)}
-    fields.update(num_lines=n2, num_windows=w2, num_kernels=k2)
+        fields = {f.name: getattr(tt, f.name) for f in dataclasses.fields(tt)}
+        fields.update(num_lines=n2, num_windows=w2, num_kernels=k2)
 
-    if n2 > n:
-        fields["line_pos"] = jnp.concatenate(
-            [tt.line_pos, line_positions(tt.spec, n, n2)], axis=1)
-        fields["line_reg"] = jnp.arange(n2, dtype=jnp.int32) % CPUWS_REGS
+        if n2 > n:
+            fields["line_pos"] = jnp.concatenate(
+                [tt.line_pos, line_positions(tt.spec, n, n2)], axis=1)
+            fields["line_reg"] = jnp.arange(n2, dtype=jnp.int32) % CPUWS_REGS
 
-    valid_of = {"pim_reads": "pim_r_valid", "pim_writes": "pim_w_valid",
-                "cpu_reads": "cpu_r_valid", "cpu_writes": "cpu_w_valid"}
-    for key, width in widths.items():
-        ids = fields[key]
-        a, a2 = ids.shape[1], width or ids.shape[1]
-        if a2 < a:
-            raise ValueError(f"cannot shrink {key} slots: {a} -> {a2}")
-        pad = ((0, w2 - w), (0, a2 - a))
-        fields[key] = jnp.pad(ids, pad, constant_values=-1)
-        fields[valid_of[key]] = jnp.pad(fields[valid_of[key]], pad)
+        valid_of = {"pim_reads": "pim_r_valid", "pim_writes": "pim_w_valid",
+                    "cpu_reads": "cpu_r_valid", "cpu_writes": "cpu_w_valid"}
+        for key, width in widths.items():
+            ids = fields[key]
+            a, a2 = ids.shape[1], width or ids.shape[1]
+            if a2 < a:
+                raise ValueError(f"cannot shrink {key} slots: {a} -> {a2}")
+            pad = ((0, w2 - w), (0, a2 - a))
+            fields[key] = jnp.pad(ids, pad, constant_values=-1)
+            fields[valid_of[key]] = jnp.pad(fields[valid_of[key]], pad)
 
-    fields["kernel_id"] = jnp.pad(tt.kernel_id, (0, w2 - w))
-    fields["kernel_start"] = jnp.pad(tt.kernel_start, (0, w2 - w))
-    fields["kernel_end"] = jnp.pad(tt.kernel_end, (0, w2 - w))
-    # Zero-padding the packed words IS packing the zero-padded boolean rows:
-    # the original last word's pad bits are already zero (the invariant).
-    fields["pre_writes"] = jnp.pad(tt.pre_writes, ((0, k2 - k), (0, n2 - n)))
-    fields["pre_writes_words"] = jnp.pad(
-        tt.pre_writes_words,
-        ((0, k2 - k), (0, packed_words(n2) - packed_words(n))))
-    for key in ("pim_instr", "cpu_instr", "cpu_priv",
-                "pim_uniq_r", "pim_uniq_w", "pim_uniq"):
-        fields[key] = jnp.pad(fields[key], (0, w2 - w))
-    fields["window_valid"] = jnp.pad(tt.window_valid, (0, w2 - w))
-    return TraceTensors(**fields)
+        fields["kernel_id"] = jnp.pad(tt.kernel_id, (0, w2 - w))
+        fields["kernel_start"] = jnp.pad(tt.kernel_start, (0, w2 - w))
+        fields["kernel_end"] = jnp.pad(tt.kernel_end, (0, w2 - w))
+        # Zero-padding the packed words IS packing the zero-padded boolean
+        # rows: the original last word's pad bits are already zero (the
+        # invariant).
+        fields["pre_writes"] = jnp.pad(tt.pre_writes,
+                                       ((0, k2 - k), (0, n2 - n)))
+        fields["pre_writes_words"] = jnp.pad(
+            tt.pre_writes_words,
+            ((0, k2 - k), (0, packed_words(n2) - packed_words(n))))
+        for key in ("pim_instr", "cpu_instr", "cpu_priv",
+                    "pim_uniq_r", "pim_uniq_w", "pim_uniq"):
+            fields[key] = jnp.pad(fields[key], (0, w2 - w))
+        fields["window_valid"] = jnp.pad(tt.window_valid, (0, w2 - w))
+        return TraceTensors(**fields)
 
 
 def bucket_shapes(

@@ -63,6 +63,7 @@ from typing import Any, Iterable, Sequence
 from repro.core.coherence import LazyPIMConfig
 from repro.core.mechanisms import SimResult, finalize_result
 from repro.core.signatures import SignatureSpec
+from repro.runtime import spans
 from repro.sim import engine as _engine
 from repro.sim import mesh as _mesh
 from repro.sim.costmodel import HWParams
@@ -80,6 +81,8 @@ __all__ = [
 # accepts this version and (for pre-stamp golden artifacts) a missing field;
 # anything else is a named ResultSetSchemaError, never a raw KeyError.
 RESULTSET_SCHEMA_VERSION = 1
+
+_TRACE_IDS = itertools.count()
 
 
 class ResultSetSchemaError(ValueError):
@@ -452,7 +455,12 @@ class Study:
     grammar.  Construction validates the spec; :meth:`plan` predicts the
     execution/compile shape; :meth:`run` executes through the bucketed
     stacked-dispatch engine (or the sequential reference with
-    ``engine="sequential"``)."""
+    ``engine="sequential"``).
+
+    In a profiler trace, :meth:`traces`, :meth:`bucket_lanes` and the
+    batched :meth:`run` are the spans ``repro:traces``,
+    ``repro:bucket_lanes`` and ``repro:run``, each with ``study`` set to
+    :attr:`trace_id`; a call answered from the study's cache opens none."""
 
     workloads: Sequence
     hw: HWParams | HWGrid | Sequence[HWParams] | None = None
@@ -508,6 +516,8 @@ class Study:
         self._lazys = lazys
         self._tts: list[TraceTensors] | None = None
         self._bls: list[BucketLanes] | None = None
+        # The ``study=`` value of this study's spans in a profiler trace.
+        self.trace_id = next(_TRACE_IDS)
 
     # -- axis materialization ----------------------------------------------
 
@@ -515,14 +525,15 @@ class Study:
         """Prepared TraceTensors of the workload axis (cached)."""
         if self._tts is None:
             tts = []
-            for e in self._entries:
-                if isinstance(e, TraceTensors):
-                    tts.append(e)
-                    continue
-                trace = make_trace(e.app, e.graph,
-                                   threads=e.threads or self.threads,
-                                   **dict(e.trace_kw))
-                tts.append(prepare(trace, e.spec or self.spec))
+            with spans.span("traces", study=self.trace_id):
+                for e in self._entries:
+                    if isinstance(e, TraceTensors):
+                        tts.append(e)
+                        continue
+                    trace = make_trace(e.app, e.graph,
+                                       threads=e.threads or self.threads,
+                                       **dict(e.trace_kw))
+                    tts.append(prepare(trace, e.spec or self.spec))
             self._tts = tts
         return self._tts
 
@@ -603,18 +614,19 @@ class Study:
             tts, hws = self.traces(), self.hw_points()
             lazys, lanes = self.lazy_points(), self._lanes()
             out = []
-            for idx, shape in bucket_shapes(tts):
-                members = set(idx)
-                sel = [j for j, lane in enumerate(lanes)
-                       if lane[0] in members]
-                if not sel:
-                    continue
-                padded = {w: pad_trace(tts[w], **shape) for w in idx}
-                out.append(BucketLanes(
-                    shape=shape, lane_points=sel,
-                    traces=[padded[lanes[j][0]] for j in sel],
-                    hws=[hws[lanes[j][1]] for j in sel],
-                    lazys=[lazys[lanes[j][2]] for j in sel]))
+            with spans.span("bucket_lanes", study=self.trace_id):
+                for idx, shape in bucket_shapes(tts):
+                    members = set(idx)
+                    sel = [j for j, lane in enumerate(lanes)
+                           if lane[0] in members]
+                    if not sel:
+                        continue
+                    padded = {w: pad_trace(tts[w], **shape) for w in idx}
+                    out.append(BucketLanes(
+                        shape=shape, lane_points=sel,
+                        traces=[padded[lanes[j][0]] for j in sel],
+                        hws=[hws[lanes[j][1]] for j in sel],
+                        lazys=[lazys[lanes[j][2]] for j in sel]))
             self._bls = out
         return self._bls
 
@@ -719,41 +731,50 @@ class Study:
     def _run_batched(self, on_dispatch=None,
                      devices: int | None = None) -> ResultSet:
         tts, lanes = self.traces(), self._lanes()
+        bls = self.bucket_lanes()
         resolved = _mesh.resolve_devices(devices)
         points: list[StudyPoint | None] = [None] * len(lanes)
-        for bl in self.bucket_lanes():
-            n = len(bl.traces)
-            d = _mesh.devices_for(n, resolved)
-            width = _mesh.mesh_lane_width(n, d)
-            traces, hws, lazys = bl.traces, bl.hws, bl.lazys
-            if width > n:
-                # Mesh pad lanes: all-sentinel masked traces (zero
-                # contribution) carrying the study's static lazy flags so
-                # they ride the same compiled dataflow.  Appended past
-                # lane_points, so the result loop below never reads them.
-                static = {f: getattr(self._lazys[0], f)
-                          for f in _engine._LAZY_STATIC_FIELDS}
-                pads = [dummy_lane_triple(traces[0].spec, bl.shape, static)
-                        for _ in range(width - n)]
-                traces = traces + [p[0] for p in pads]
-                hws = hws + [p[1] for p in pads]
-                lazys = lazys + [p[2] for p in pads]
-            stacked = _engine.neutral_trace(_engine.stack_traces(traces))
-            shw = _engine.stack_hw(hws)
-            scfg = _engine.stack_lazy(lazys)
-            boundary = None
-            if on_dispatch is not None:
-                def boundary(m, thunk, _shape=bl.shape, _n=n, _d=d):
-                    return on_dispatch(
-                        Dispatch(engine="batch", mechanism=m, lanes=_n,
-                                 bucket_lines=_shape["num_lines"],
-                                 devices=_d), thunk)
-            accs = _engine._sweep_accs(stacked, shw, self.mechanisms, scfg,
-                                       boundary=boundary, devices=d)
-            for pos, j in enumerate(bl.lane_points):
-                w = lanes[j][0]
-                res = {m: finalize_result(tts[w].name, m,
-                                          {k: v[pos] for k, v in acc.items()})
-                       for m, acc in accs.items()}
-                points[j] = self._make_point(j, res)
+        with spans.span("run", study=self.trace_id):
+            for bl in bls:
+                n = len(bl.traces)
+                d = _mesh.devices_for(n, resolved)
+                with spans.span("stack"):
+                    stacked, shw, scfg = self._stack_lanes(bl, n, d)
+                boundary = None
+                if on_dispatch is not None:
+                    def boundary(m, thunk, _shape=bl.shape, _n=n, _d=d):
+                        return on_dispatch(
+                            Dispatch(engine="batch", mechanism=m, lanes=_n,
+                                     bucket_lines=_shape["num_lines"],
+                                     devices=_d), thunk)
+                accs = _engine._sweep_accs(stacked, shw, self.mechanisms,
+                                           scfg, boundary=boundary, devices=d)
+                with spans.span("finalize"):
+                    for pos, j in enumerate(bl.lane_points):
+                        w = lanes[j][0]
+                        res = {m: finalize_result(
+                                   tts[w].name, m,
+                                   {k: v[pos] for k, v in acc.items()})
+                               for m, acc in accs.items()}
+                        points[j] = self._make_point(j, res)
         return ResultSet(points, self.mechanisms)
+
+    def _stack_lanes(self, bl: BucketLanes, n: int, d: int):
+        """One bucket's stacked (trace, hw, lazy) pytrees, its lane axis
+        padded to the ``d``-device mesh multiple."""
+        width = _mesh.mesh_lane_width(n, d)
+        traces, hws, lazys = bl.traces, bl.hws, bl.lazys
+        if width > n:
+            # Mesh pad lanes: all-sentinel masked traces (zero
+            # contribution) carrying the study's static lazy flags so
+            # they ride the same compiled dataflow.  Appended past
+            # lane_points, so the result loop never reads them.
+            static = {f: getattr(self._lazys[0], f)
+                      for f in _engine._LAZY_STATIC_FIELDS}
+            pads = [dummy_lane_triple(traces[0].spec, bl.shape, static)
+                    for _ in range(width - n)]
+            traces = traces + [p[0] for p in pads]
+            hws = hws + [p[1] for p in pads]
+            lazys = lazys + [p[2] for p in pads]
+        return (_engine.neutral_trace(_engine.stack_traces(traces)),
+                _engine.stack_hw(hws), _engine.stack_lazy(lazys))

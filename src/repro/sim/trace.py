@@ -57,6 +57,7 @@ import dataclasses
 
 import numpy as np
 
+from repro.runtime import spans
 from repro.sim import synth
 from repro.sim.synth import AR, AW, BR, BW, MAX_SIG_ADDRS  # noqa: F401  (re-export)
 from repro.sim.synth import APP_CPU_WRITES  # noqa: F401  (re-export)
@@ -201,28 +202,35 @@ def make_trace(
     ``capture/*`` apps are *recorded* from live model execution
     (:mod:`repro.capture`) instead of synthesized; unknown ``capture/``
     specs raise the same admission-time ValueError unknown apps do.
+
+    In a profiler trace this is the ``repro:synth`` span: plan build, graph
+    generation and the synthesis dispatch (the device program runs on past
+    it; the first host read in ``prepare`` waits for it).
     """
-    if app.startswith("capture/"):
-        if graph_name is not None:
-            raise ValueError(f"{app!r} is a captured workload: graph_name "
-                             f"must be None, got {graph_name!r}")
-        from repro import capture
+    with spans.span("synth"):
+        if app.startswith("capture/"):
+            if graph_name is not None:
+                raise ValueError(f"{app!r} is a captured workload: "
+                                 f"graph_name must be None, got "
+                                 f"{graph_name!r}")
+            from repro import capture
 
-        return capture.capture_trace(
-            app, threads=threads, seed=seed, num_kernels=num_kernels,
-            windows_per_kernel=windows_per_kernel, scale=scale,
-            cpu_reuse=cpu_reuse, backend=backend)
-    plan, edges, name = build_plan(app, graph_name, threads, num_kernels,
-                                   windows_per_kernel, seed, scale, cpu_reuse)
-    if backend == "jax":
-        arrays = synth.synthesize(plan, seed, edges)
-    elif backend == "ref":
-        from repro.sim import _traceref
+            return capture.capture_trace(
+                app, threads=threads, seed=seed, num_kernels=num_kernels,
+                windows_per_kernel=windows_per_kernel, scale=scale,
+                cpu_reuse=cpu_reuse, backend=backend)
+        plan, edges, name = build_plan(app, graph_name, threads,
+                                       num_kernels, windows_per_kernel, seed,
+                                       scale, cpu_reuse)
+        if backend == "jax":
+            arrays = synth.synthesize(plan, seed, edges)
+        elif backend == "ref":
+            from repro.sim import _traceref
 
-        arrays = _traceref.synthesize_ref(plan, seed, edges)
-    else:
-        raise ValueError(f"unknown backend {backend!r}")
-    return _assemble(plan, name, arrays)
+            arrays = _traceref.synthesize_ref(plan, seed, edges)
+        else:
+            raise ValueError(f"unknown backend {backend!r}")
+        return _assemble(plan, name, arrays)
 
 
 def make_graph_trace(app, graph_name, threads=16, num_kernels=24,
