@@ -646,14 +646,16 @@ def _uniq_union_count(a: np.ndarray, b: np.ndarray) -> np.ndarray:
 
 def _pack_rows_np(bits: np.ndarray) -> np.ndarray:
     """(..., n) bool -> (..., ceil(n/32)) uint32, same bit order as
-    :func:`pack_bitmap` (numpy, prepare-time)."""
-    n = bits.shape[-1]
-    pad = (-n) % 32
+    :func:`pack_bitmap` (numpy, prepare-time).
+
+    One ``packbits`` pass over the truth values (little bit order, zero pad
+    bits), the row bytes padded to whole words and read as little-endian
+    uint32 words."""
+    b = np.packbits(np.asarray(bits, dtype=bool), axis=-1, bitorder="little")
+    pad = (-b.shape[-1]) % 4
     if pad:
-        widths = [(0, 0)] * (bits.ndim - 1) + [(0, pad)]
-        bits = np.pad(bits, widths)
-    b = bits.reshape(*bits.shape[:-1], -1, 32).astype(np.uint32)
-    return (b << np.arange(32, dtype=np.uint32)).sum(-1, dtype=np.uint64).astype(np.uint32)
+        b = np.pad(b, [(0, 0)] * (b.ndim - 1) + [(0, pad)])
+    return b.view("<u4").astype(np.uint32, copy=False)
 
 
 def line_positions(spec: SignatureSpec, start: int, stop: int) -> jax.Array:

@@ -176,6 +176,71 @@ def test_uniq_count_vectorized_matches_loop():
                                   P._uniq_union_count_loop(rows, other))
 
 
+def _pack_rows_widen_shift_sum(bits):
+    """The seed formula of ``_pack_rows_np``: widen to uint32, shift each bit
+    to its place, sum 32-wide rows (kept here as the reference)."""
+    n = bits.shape[-1]
+    pad = (-n) % 32
+    if pad:
+        bits = np.pad(bits, [(0, 0)] * (bits.ndim - 1) + [(0, pad)])
+    b = bits.reshape(*bits.shape[:-1], -1, 32).astype(np.uint32)
+    return (b << np.arange(32, dtype=np.uint32)).sum(
+        -1, dtype=np.uint64).astype(np.uint32)
+
+
+def _check_pack_rows(bits):
+    n = bits.shape[-1]
+    words = P._pack_rows_np(bits)
+    assert words.dtype == np.uint32
+    assert words.flags.c_contiguous
+    assert words.shape == (*bits.shape[:-1], P.packed_words(n))
+    np.testing.assert_array_equal(words, _pack_rows_widen_shift_sum(bits))
+    flat = jnp.asarray(bits).reshape(-1, n)
+    by_jax = np.asarray(jax.vmap(P.pack_bitmap)(flat)).reshape(words.shape)
+    np.testing.assert_array_equal(words, by_jax)
+    # pad bits beyond n are zero
+    unpacked = (words[..., :, None] >> np.arange(32, dtype=np.uint32)) & 1
+    assert not unpacked.reshape(*words.shape[:-1], -1)[..., n:].any()
+
+
+@pytest.mark.parametrize("n", [1, 31, 32, 33, 1000, 16 * 32 + 16])
+@pytest.mark.parametrize("rows", [1, 3, 24])
+@pytest.mark.parametrize("density", [0.0, 0.01, 0.5, 1.0])
+def test_pack_rows_np_matches_references(n, rows, density):
+    rng = np.random.default_rng(n * 100 + rows)
+    _check_pack_rows(rng.random((rows, n)) < density)
+
+
+@pytest.mark.parametrize("layout", ["1d", "column_slice", "int01"])
+def test_pack_rows_np_layouts(layout):
+    rng = np.random.default_rng(7)
+    bits = rng.random((3, 2 * 1000 + 1)) < 0.3
+    if layout == "1d":
+        bits = bits[0]
+    elif layout == "column_slice":
+        bits = bits[:, ::2]
+        assert not bits.flags.c_contiguous
+    else:
+        bits = bits.astype(np.int32)
+    _check_pack_rows(bits)
+
+
+@pytest.mark.parametrize("backend", ["ref", "jax"])
+def test_prepare_packs_pre_writes(backend):
+    trace = make_htap_trace("htap128", threads=16, num_kernels=3,
+                            windows_per_kernel=2, scale=0.004,
+                            backend=backend)
+    assert isinstance(trace.pre_writes,
+                      np.ndarray if backend == "ref" else jax.Array)
+    prepped = prepare(trace)
+    words = np.asarray(prepped.pre_writes_words)
+    assert words.shape == (3, P.packed_words(trace.num_lines))
+    np.testing.assert_array_equal(
+        words, np.asarray(jax.vmap(P.pack_bitmap)(prepped.pre_writes)))
+    np.testing.assert_array_equal(
+        words, _pack_rows_widen_shift_sum(np.asarray(trace.pre_writes)))
+
+
 # ---------------------------------------------------------------------------
 # Full-simulation differentials: every accumulator of every mechanism
 # ---------------------------------------------------------------------------
