@@ -9,13 +9,36 @@ program under test must produce the same access lists from the same
 simulated results this trace yields.
 
 ``make_trace(app, graph, seed=..., threads=..., num_kernels=...,
-windows_per_kernel=..., scale=..., cpu_reuse=...)`` returns a dict of numpy
-arrays with the same keyword defaults as the program's workload specs.
+windows_per_kernel=..., scale=..., cpu_reuse=..., **extra)`` returns a dict
+of numpy arrays with the same keyword defaults as the program's workload
+specs.
+
+Families and graph inputs are found by name, so a new one is a new file:
+
+* an ``app`` that is not built in is ``apps/<app>.py`` beside this file,
+  whose ``make_trace(app, graph, *, seed, threads, num_kernels,
+  windows_per_kernel, scale, cpu_reuse, **extra)`` returns the same dict
+  (``scale`` and ``cpu_reuse`` are ``None`` where the workload leaves them
+  to the family's defaults);
+* a ``graph`` that is not in :data:`GRAPH_SHAPES` is ``graphs/<graph>.py``,
+  whose ``make_graph(seed, scale, **extra)`` returns ``(num_nodes, edges)``
+  with edges an (E, 2) int32 array sorted by source; the built-in graph,
+  frontier and mtmix families then run on it unchanged.  Such a file may
+  leave the study seed aside and build one graph from its own keys.
+
+Workload keys beyond the keywords above (``extra``) go to the family or
+graph file found by name; a built-in family or graph given one raises
+``TypeError``.  Those files may use this module's helpers (the Threefry
+streams, ``_Trace``, ``_pad``) and import nothing of the program.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import math
+import pathlib
+import re
 import zlib
 
 import numpy as np
@@ -33,9 +56,13 @@ VPL = 8   # 8-byte vertex values per 64 B line
 EPL = 8   # 8-byte CSR edges per line
 TUPLE_LINES = IMDB_FIELDS * 8 // 64
 
+HERE = pathlib.Path(__file__).resolve().parent
+FILE_NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+
 GRAPH_APPS = ("pagerank", "radii", "components")
 FRONTIER_APPS = ("bfs", "sssp")
 HTAP_APPS = ("htap128", "htap192", "htap256")
+BUILT_IN_APPS = GRAPH_APPS + FRONTIER_APPS + HTAP_APPS + ("mtmix", "htap_stream")
 
 # ---------------------------------------------------------------------------
 # Threefry-2x32 counter streams
@@ -88,7 +115,31 @@ def _one(x):
 # ---------------------------------------------------------------------------
 
 
-def make_graph(name, seed, scale=1.0):
+@functools.cache
+def _by_name(kind, name):
+    """The module ``<kind>/<name>.py`` beside this file, loaded once."""
+    path = HERE / kind / f"{name}.py"
+    if not (isinstance(name, str) and FILE_NAME.match(name) and path.is_file()):
+        raise ValueError(f"unknown {kind[:-1]} {name!r}: not built in and "
+                         f"no {kind}/{name}.py beside {__name__}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_reference_{kind}_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def make_graph(name, seed, scale=1.0, **extra):
+    if name not in GRAPH_SHAPES:
+        n, ed = _by_name("graphs", name).make_graph(seed, scale, **extra)
+        ed = np.asarray(ed)
+        if (ed.dtype != np.int32 or ed.ndim != 2 or ed.shape[1] != 2
+                or np.any(ed[1:, 0] < ed[:-1, 0])):
+            raise ValueError(f"graph {name!r}: edges must be an (E, 2) int32 "
+                             "array sorted by source")
+        return int(n), ed
+    if extra:
+        raise TypeError(f"graph {name!r} takes no keys {sorted(extra)}")
     nodes, edges = GRAPH_SHAPES[name]
     n = max(16, int(nodes * scale))
     e = max(32, int(edges * scale))
@@ -160,8 +211,8 @@ class _Trace:
 # ---------------------------------------------------------------------------
 
 
-def _graph(app, graph, threads, K, wpk, seed, scale, reuse):
-    n, edges = make_graph(graph, seed, scale)
+def _graph(app, graph, threads, K, wpk, seed, scale, reuse, **extra):
+    n, edges = make_graph(graph, seed, scale, **extra)
     E = len(edges)
     vl, fl, el = _graph_layout(n, E)
     pn, fb, eb = vl, 2 * vl, 2 * vl + fl
@@ -210,8 +261,8 @@ def _graph(app, graph, threads, K, wpk, seed, scale, reuse):
         cpu_priv_miss_rate=0.002, cpu_reuse=reuse))
 
 
-def _frontier(app, graph, threads, K, wpk, seed, scale, reuse):
-    n, edges = make_graph(graph, seed, scale)
+def _frontier(app, graph, threads, K, wpk, seed, scale, reuse, **extra):
+    n, edges = make_graph(graph, seed, scale, **extra)
     E = len(edges)
     vl, fl, el = _graph_layout(n, E)
     pn, fb, eb = vl, 2 * vl, 2 * vl + fl
@@ -347,10 +398,10 @@ def _stream(app, threads, K, wpk, seed, scale, reuse):
         cpu_priv_miss_rate=0.0015, cpu_reuse=reuse))
 
 
-def _mtmix(app, graph, threads, K, wpk, seed, scale, reuse):
+def _mtmix(app, graph, threads, K, wpk, seed, scale, reuse, **extra):
     if K < 2:
         raise ValueError("mtmix needs num_kernels >= 2")
-    n, edges = make_graph(graph, seed, scale)
+    n, edges = make_graph(graph, seed, scale, **extra)
     E = len(edges)
     vl, fl, el = _graph_layout(n, E)
     tl = 2 * vl + fl
@@ -419,23 +470,29 @@ def _mtmix(app, graph, threads, K, wpk, seed, scale, reuse):
 
 
 def make_trace(app, graph=None, *, seed=0, threads=16, num_kernels=24,
-               windows_per_kernel=3, scale=None, cpu_reuse=None) -> dict:
+               windows_per_kernel=3, scale=None, cpu_reuse=None,
+               **extra) -> dict:
     """The trace of one workload, as a dict of numpy arrays (see module
     docstring); ``pre_lines[k]`` lists the lines kernel ``k``'s
     inter-kernel phase writes."""
+    if app not in BUILT_IN_APPS:
+        return _by_name("apps", app).make_trace(
+            app, graph, seed=seed, threads=threads, num_kernels=num_kernels,
+            windows_per_kernel=windows_per_kernel, scale=scale,
+            cpu_reuse=cpu_reuse, **extra)
     if scale is None:
         scale = 0.01 if app in HTAP_APPS + ("htap_stream",) else 1.0
     if cpu_reuse is None:
         cpu_reuse = 8.0 if app == "htap_stream" else 6.0
     args = (threads, num_kernels, windows_per_kernel, seed, scale, cpu_reuse)
     if app in GRAPH_APPS:
-        return _graph(app, graph, *args)
+        return _graph(app, graph, *args, **extra)
     if app in FRONTIER_APPS:
-        return _frontier(app, graph, *args)
+        return _frontier(app, graph, *args, **extra)
     if app == "mtmix":
-        return _mtmix(app, graph, *args)
+        return _mtmix(app, graph, *args, **extra)
+    if extra:
+        raise TypeError(f"{app!r} takes no keys {sorted(extra)}")
     if app in HTAP_APPS:
         return _htap(app, *args)
-    if app == "htap_stream":
-        return _stream(app, *args)
-    raise ValueError(f"unknown app {app!r}")
+    return _stream(app, *args)

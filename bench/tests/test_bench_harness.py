@@ -1,6 +1,7 @@
 """CPU rehearsal of the benchmark harness at tiny sizes: the files it finds
 by name, the contract of its result line, its refusal of the CPU backend,
-its accounting of real work, and that a new cell needs only new files."""
+its accounting of real work, and that a new cell, a new workload family or
+a new graph input needs only new files."""
 
 import json
 import pathlib
@@ -61,10 +62,13 @@ def test_benchmark_json_has_the_contract_keys_and_names(bench):
         e2e = {m["name"] for m in harness.cell_metrics(bench, w, "end_to_end")}
         assert "setup_s" in e2e and len(e2e) >= 2
         assert harness.cell_metrics(bench, w, "per_layer")
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(1, len(bench["workloads"]) // 2)
     assert len(json.dumps(bench)) < 64 * 1024
 
 
-@pytest.mark.parametrize("cell", ["large-bwsweep", "htap-fig7"])
+@pytest.mark.parametrize("cell", [w["name"] for w in
+                                  harness.load_benchmark(ROOT)["workloads"]])
 def test_every_config_and_mix_builds_at_a_tiny_size(bench, cell):
     from repro.sim.trace import make_trace
 
@@ -91,12 +95,100 @@ def _same_trace(prog, ref):
     ("bfs", "arxiv"), ("sssp", "enron"), ("htap_stream", None),
     ("mtmix", "gnutella")])
 def test_the_reference_synthesis_matches_every_other_family(app, graph):
-    """The families no cell runs yet, so that a later configuration can
-    name them in a data file alone."""
+    """The built-in families no cell runs yet, so that a later configuration
+    can name them in a data file alone (a new family is a new file:
+    ``test_a_new_family_and_graph_input_need_only_new_files``)."""
     from repro.sim.trace import make_trace
 
     kw = dict(TINY, seed=2**31 + 5, threads=16)
     _same_trace(make_trace(app, graph, **kw), RS.make_trace(app, graph, **kw))
+
+
+TOY_APP = """\
+from reference import synth as RS
+
+
+def make_trace(app, graph, *, seed, threads, num_kernels, windows_per_kernel,
+               scale, cpu_reuse, flavour):
+    tr = RS.make_trace(flavour, graph, seed=seed, threads=threads,
+                       num_kernels=num_kernels,
+                       windows_per_kernel=windows_per_kernel, scale=scale,
+                       cpu_reuse=cpu_reuse)
+    return {**tr, "name": f"{app}-{graph}"}
+"""
+
+TOY_GRAPH = """\
+from reference import synth as RS
+
+
+def make_graph(seed, scale, *, like):
+    return RS.make_graph(like, seed, scale)
+"""
+
+
+def _same_fields(a, b):
+    assert a.keys() == b.keys()
+    for k in a.keys() - {"name"}:
+        x, y = a[k], b[k]
+        if isinstance(x, list):
+            assert len(x) == len(y), k
+            assert all(np.array_equal(p, q) for p, q in zip(x, y)), k
+        else:
+            assert np.asarray(x).dtype == np.asarray(y).dtype, k
+            assert np.array_equal(x, y), k
+
+
+def test_a_new_family_and_graph_input_need_only_new_files(tmp_path,
+                                                          monkeypatch):
+    """A workload family and a graph input, each one new file beside a copy
+    of the reference, with a key of its own passed through: the reference
+    finds both, runs a built-in family on the new graph as on arxiv's, and
+    no file that was there changed.  (The families key their random
+    streams by the graph's name, so bfs on the new graph is compared with
+    bfs on arxiv's graph under the new name.)"""
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*")
+              if p.is_file()}
+    ref = tmp_path / "bench" / "reference"
+    (ref / "apps").mkdir()
+    (ref / "graphs").mkdir()
+    (ref / "apps" / "toyfam.py").write_text(TOY_APP)
+    (ref / "graphs" / "toygraph.py").write_text(TOY_GRAPH)
+    monkeypatch.syspath_prepend(str(tmp_path / "bench"))
+    for mod in [m for m in sys.modules if m.split(".")[0] == "reference"]:
+        monkeypatch.delitem(sys.modules, mod)
+    from reference import synth as copy
+
+    assert pathlib.Path(copy.__file__).parent == ref
+    kw = dict(TINY, seed=2**31 + 9, threads=16)
+    n, edges = copy.make_graph("toygraph", kw["seed"], 1.0, like="arxiv")
+    want_n, want_edges = RS.make_graph("arxiv", kw["seed"], 1.0)
+    assert n == want_n and np.array_equal(edges, want_edges)
+    got = copy.make_trace("bfs", "toygraph", like="arxiv", **kw)
+    with monkeypatch.context() as m:
+        m.setattr(copy, "make_graph",
+                  lambda name, seed, scale: RS.make_graph("arxiv", seed, scale))
+        want = copy.make_trace("bfs", "toygraph", **kw)
+    assert got["name"] == want["name"] == "bfs-toygraph"
+    _same_fields(got, want)
+    fam = copy.make_trace("toyfam", "arxiv", flavour="sssp", **kw)
+    assert fam["name"] == "toyfam-arxiv"
+    _same_fields(fam, RS.make_trace("sssp", "arxiv", **kw))
+    with pytest.raises(ValueError, match="toyfam"):
+        RS.make_trace("toyfam", "arxiv", flavour="sssp", **kw)
+    with pytest.raises(TypeError):
+        copy.make_trace("bfs", "toygraph", like="arxiv", colour=1, **kw)
+    after = {p: p.read_bytes() for p in before}
+    assert after == before
+
+
+@pytest.mark.parametrize("app,graph", [
+    ("htap128", None), ("htap_stream", None), ("bfs", "arxiv"),
+    ("pagerank", "enron"), ("mtmix", "gnutella")])
+def test_a_built_in_family_refuses_a_key_it_does_not_take(app, graph):
+    with pytest.raises(TypeError, match="graph_seed"):
+        RS.make_trace(app, graph, graph_seed=3, **TINY)
 
 
 def test_study_seeds_are_fixed_by_the_run_seed_and_fit_31_bits():
