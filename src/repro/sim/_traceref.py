@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.runtime import spans
 from repro.sim import synth as S
 from repro.sim.synth import (
     AR,
@@ -381,11 +382,12 @@ ARRAY_FNS_REF = {
 }
 
 
-def synthesize_ref(plan, seed: int = 0, edges: np.ndarray | None = None) -> dict:
-    """Generate the full trace-array dict with the sequential numpy loops."""
+def synthesize_ref(plan, seed: int = 0, edges=None) -> dict:
+    """Generate the full trace-array dict with the sequential numpy loops;
+    a device-resident ``edges`` is read to the host (and counted)."""
     keys = derive_keys(plan.app, getattr(plan, "graph_name", None), seed,
                        type(plan).STREAMS)
     fn = ARRAY_FNS_REF[type(plan)]
     if type(plan) in (S.HtapPlan, S.StreamPlan):
         return fn(plan, keys)
-    return fn(plan, keys, edges)
+    return fn(plan, keys, spans.d2h(edges))

@@ -69,7 +69,8 @@ from repro.sim import mesh as _mesh
 from repro.sim.costmodel import HWParams
 from repro.sim.prep import (TraceTensors, bucket_shapes, dummy_lane_triple,
                             pad_trace, prepare)
-from repro.sim.trace import ALL_APPS, GRAPH_INPUTS, make_trace
+from repro.sim.trace import (ALL_APPS, GENERATED_GRAPHS, GRAPH_INPUTS,
+                             make_trace, workload_key_error)
 
 __all__ = [
     "Study", "StudyPlan", "StudyPoint", "ResultSet", "ResultSetSchemaError",
@@ -190,7 +191,8 @@ def _parse_workload(entry, i: int) -> Workload | TraceTensors:
             raise ValueError(
                 f"workloads[{i}]: unknown workload {entry!r} (want "
                 f"'<app>' or '<app>-<graph>' with app in "
-                f"{sorted(ALL_APPS)} and graph in {GRAPH_INPUTS})")
+                f"{sorted(ALL_APPS)} and graph in "
+                f"{GRAPH_INPUTS + GENERATED_GRAPHS})")
         entry = Workload(app, graph)
     elif isinstance(entry, (tuple, list)) and len(entry) == 2:
         app, graph = entry
@@ -203,12 +205,16 @@ def _parse_workload(entry, i: int) -> Workload | TraceTensors:
     if app not in ALL_APPS:
         raise ValueError(f"workloads[{i}]: unknown app {app!r} "
                          f"(know {sorted(ALL_APPS)})")
-    if ALL_APPS[app] and graph not in GRAPH_INPUTS:
+    if ALL_APPS[app] and graph not in GRAPH_INPUTS + GENERATED_GRAPHS:
         raise ValueError(f"workloads[{i}]: app {app!r} needs a graph input "
-                         f"from {GRAPH_INPUTS}, got {graph!r}")
+                         f"from {GRAPH_INPUTS} or a generated graph from "
+                         f"{GENERATED_GRAPHS}, got {graph!r}")
     if not ALL_APPS[app] and graph is not None:
         raise ValueError(f"workloads[{i}]: app {app!r} is a table workload; "
                          f"graph must be None, got {graph!r}")
+    why = workload_key_error(app, graph, dict(entry.trace_kw))
+    if why is not None:
+        raise ValueError(f"workloads[{i}]: {why}")
     return entry
 
 

@@ -403,8 +403,8 @@ FRONTIER_PARAMS = {
 
 
 def build_graph_plan(app, graph_name, threads=16, num_kernels=24, wpk=3,
-                     seed=0, scale=1.0, cpu_reuse=6.0):
-    g = G.make_graph(graph_name, seed=seed, scale=scale)
+                     seed=0, scale=1.0, cpu_reuse=6.0, **graph_kw):
+    g = G.make_graph(graph_name, seed=seed, scale=scale, **graph_kw)
     lay = G.layout_for_graph(g)
     raw_w, hot_bias = APP_CPU_WRITES[app]
     frontier_frac = {"pagerank": 1.0, "radii": 0.45, "components": 0.6}[app]
@@ -425,10 +425,10 @@ def build_graph_plan(app, graph_name, threads=16, num_kernels=24, wpk=3,
 
 
 def build_frontier_plan(app, graph_name, threads=16, num_kernels=24, wpk=3,
-                        seed=0, scale=1.0, cpu_reuse=6.0):
+                        seed=0, scale=1.0, cpu_reuse=6.0, **graph_kw):
     import math
 
-    g = G.make_graph(graph_name, seed=seed, scale=scale)
+    g = G.make_graph(graph_name, seed=seed, scale=scale, **graph_kw)
     lay = G.layout_for_graph(g)
     peak_epw, peak_pos, width, relax, qraw = FRONTIER_PARAMS[app]
     # BFS-level bell: tiny frontiers at the root and the fringe, a burst of
@@ -479,13 +479,13 @@ def build_stream_plan(app="htap_stream", threads=16, num_kernels=24, wpk=3,
 
 
 def build_mt_plan(app, graph_name, threads=16, num_kernels=24, wpk=3,
-                  seed=0, scale=1.0, cpu_reuse=6.0):
+                  seed=0, scale=1.0, cpu_reuse=6.0, **graph_kw):
     if num_kernels < 2:
         # tenant B would get zero kernels — the vectorized generator's
         # tenant-select gathers need at least one kernel per tenant
         raise ValueError(f"mtmix interleaves two tenants: num_kernels must "
                          f"be >= 2, got {num_kernels}")
-    g = G.make_graph(graph_name, seed=seed, scale=scale)
+    g = G.make_graph(graph_name, seed=seed, scale=scale, **graph_kw)
     lay = G.mt_layout_for_graph(g)
     ka = (num_kernels + 1) // 2   # tenant A runs even kernels
     kb = num_kernels // 2
@@ -886,18 +886,20 @@ def _compiled(plan):
     return jax.jit(lambda keys, edges: fn(plan, keys, edges))
 
 
-def generator(plan, seed: int = 0, edges: np.ndarray | None = None):
+def generator(plan, seed: int = 0, edges: jax.Array | None = None):
     """(fn, args) producing the full trace-array dict on device — the unit
-    the trace-synthesis benchmark times (compile excluded)."""
+    the trace-synthesis benchmark times (compile excluded).  ``edges`` is the
+    graph's device-resident array (:func:`repro.sim.graphs.make_graph`), so
+    synthesis puts no edge bytes."""
     keys = jnp.asarray(derive_keys(
         plan.app, getattr(plan, "graph_name", None), seed, type(plan).STREAMS))
     fn = _compiled(plan)
     if type(plan) in (HtapPlan, StreamPlan):
         return fn, (keys,)
-    return fn, (keys, jnp.asarray(edges))
+    return fn, (keys, edges)
 
 
-def synthesize(plan, seed: int = 0, edges: np.ndarray | None = None) -> dict:
+def synthesize(plan, seed: int = 0, edges: jax.Array | None = None) -> dict:
     """Run the compiled generator; returns the device-array dict."""
     fn, args = generator(plan, seed, edges)
     return fn(*args)

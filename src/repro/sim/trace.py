@@ -58,12 +58,19 @@ import dataclasses
 import numpy as np
 
 from repro.runtime import spans
+from repro.sim import graphs as G
 from repro.sim import synth
 from repro.sim.synth import AR, AW, BR, BW, MAX_SIG_ADDRS  # noqa: F401  (re-export)
 from repro.sim.synth import APP_CPU_WRITES  # noqa: F401  (re-export)
 
 GRAPH_APPS = ("pagerank", "radii", "components")
 GRAPH_INPUTS = ("enron", "arxiv", "gnutella")
+# Graph inputs built from keys of their own (Graph500's Kronecker graph):
+# accepted wherever a graph input is, but in no fleet of all_workloads().
+GENERATED_GRAPHS = tuple(G.GENERATED_GRAPHS)
+# make_trace's own keywords a workload spec may set
+TRACE_KEYS = ("seed", "num_kernels", "windows_per_kernel", "scale",
+              "cpu_reuse", "backend")
 HTAP_APPS = ("htap128", "htap192", "htap256")
 FRONTIER_APPS = ("bfs", "sssp")
 STREAM_APPS = ("htap_stream",)
@@ -116,6 +123,22 @@ class WindowTrace:
         return int(self.pre_writes.shape[0])
 
 
+def workload_key_error(app: str, graph_name: str | None,
+                       keys) -> str | None:
+    """Why a workload's trace keys are refused, or ``None``: keys beyond
+    :data:`TRACE_KEYS` must be keys its graph input takes, and a generated
+    graph's required keys must all be there."""
+    known, required = G.graph_keys(graph_name) if ALL_APPS.get(app) else ((), ())
+    unknown = sorted(set(keys) - set(TRACE_KEYS) - set(known))
+    if unknown:
+        return (f"unknown trace keys {unknown} for {app!r} on "
+                f"{graph_name!r} (know {sorted(TRACE_KEYS + known)})")
+    missing = sorted(set(required) - set(keys))
+    if missing:
+        return f"graph {graph_name!r} needs the keys {missing}"
+    return None
+
+
 def build_plan(
     app: str,
     graph_name: str | None = None,
@@ -125,10 +148,13 @@ def build_plan(
     seed: int = 0,
     scale: float | None = None,
     cpu_reuse: float | None = None,
+    **graph_kw,
 ):
     """(plan, edges-or-None, display name) for any workload family, with
     the same per-family defaults ``make_trace`` applies (scale 0.01 for the
-    table families, streaming's higher ``cpu_reuse``).  The public plan
+    table families, streaming's higher ``cpu_reuse``).  ``graph_kw`` goes to
+    the graph input (a generated graph's keys); a table family or a
+    SNAP-shaped graph given one raises ``TypeError``.  The public plan
     entry point for benchmarks that drive :mod:`repro.sim.synth` directly."""
     if app.startswith("capture/"):
         raise ValueError(
@@ -137,32 +163,39 @@ def build_plan(
             f"make_trace")
     if app not in ALL_APPS:
         raise ValueError(f"unknown app {app!r} (know {sorted(ALL_APPS)})")
-    if ALL_APPS[app] and graph_name not in GRAPH_INPUTS:
+    if ALL_APPS[app] and graph_name not in GRAPH_INPUTS + GENERATED_GRAPHS:
         raise ValueError(
-            f"{app!r} needs a graph input from {GRAPH_INPUTS}, got {graph_name!r}")
+            f"{app!r} needs a graph input from {GRAPH_INPUTS} or a generated "
+            f"graph from {GENERATED_GRAPHS}, got {graph_name!r}")
     if not ALL_APPS[app] and graph_name is not None:
         raise ValueError(f"{app!r} is a table workload: graph_name must be "
                          f"None, got {graph_name!r}")
+    if not ALL_APPS[app] and graph_kw:
+        raise TypeError(f"{app!r} takes no keys {sorted(graph_kw)}")
     if scale is None:
         scale = 0.01 if app in HTAP_APPS + STREAM_APPS else 1.0
     if cpu_reuse is None:
         cpu_reuse = 8.0 if app in STREAM_APPS else 6.0
     return _build(app, graph_name, threads, num_kernels, windows_per_kernel,
-                  seed, scale, cpu_reuse)
+                  seed, scale, cpu_reuse, graph_kw)
 
 
-def _build(app, graph_name, threads, num_kernels, wpk, seed, scale, cpu_reuse):
+def _build(app, graph_name, threads, num_kernels, wpk, seed, scale, cpu_reuse,
+           graph_kw):
     if app in GRAPH_APPS:
         plan, edges = synth.build_graph_plan(
-            app, graph_name, threads, num_kernels, wpk, seed, scale, cpu_reuse)
+            app, graph_name, threads, num_kernels, wpk, seed, scale, cpu_reuse,
+            **graph_kw)
         return plan, edges, f"{app}-{graph_name}"
     if app in FRONTIER_APPS:
         plan, edges = synth.build_frontier_plan(
-            app, graph_name, threads, num_kernels, wpk, seed, scale, cpu_reuse)
+            app, graph_name, threads, num_kernels, wpk, seed, scale, cpu_reuse,
+            **graph_kw)
         return plan, edges, f"{app}-{graph_name}"
     if app in MT_APPS:
         plan, edges = synth.build_mt_plan(
-            app, graph_name, threads, num_kernels, wpk, seed, scale, cpu_reuse)
+            app, graph_name, threads, num_kernels, wpk, seed, scale, cpu_reuse,
+            **graph_kw)
         return plan, edges, f"{app}-{graph_name}"
     if app in HTAP_APPS:
         plan = synth.build_htap_plan(
@@ -192,11 +225,16 @@ def make_trace(
     scale: float | None = None,
     cpu_reuse: float | None = None,
     backend: str = "jax",
+    **graph_kw,
 ) -> WindowTrace:
     """Uniform entry point for every workload family.
 
     Graph-input families (graph/frontier/mtmix apps) need ``graph_name``;
-    table families (HTAP/streaming) don't.  ``backend="jax"`` (default)
+    table families (HTAP/streaming) don't.  A generated graph
+    (:data:`GENERATED_GRAPHS`) takes its keys as ``graph_kw``, e.g.
+    ``make_trace("bfs", "kronecker", kron_scale=21, edge_factor=16,
+    graph_seed=1)``; it is built once per process and ignores ``seed``,
+    which still draws the trace on it (fresh search roots on one graph).  ``backend="jax"`` (default)
     runs the jit-compiled on-device generator; ``backend="ref"`` the
     sequential numpy reference — bit-identical by construction and by test.
     ``capture/*`` apps are *recorded* from live model execution
@@ -204,8 +242,9 @@ def make_trace(
     specs raise the same admission-time ValueError unknown apps do.
 
     In a profiler trace this is the ``repro:synth`` span: plan build, graph
-    generation and the synthesis dispatch (the device program runs on past
-    it; the first host read in ``prepare`` waits for it).
+    generation (``repro:graph``, the first time a graph is named) and the
+    synthesis dispatch (the device program runs on past it; the first host
+    read in ``prepare`` waits for it).
     """
     with spans.span("synth"):
         if app.startswith("capture/"):
@@ -213,6 +252,8 @@ def make_trace(
                 raise ValueError(f"{app!r} is a captured workload: "
                                  f"graph_name must be None, got "
                                  f"{graph_name!r}")
+            if graph_kw:
+                raise TypeError(f"{app!r} takes no keys {sorted(graph_kw)}")
             from repro import capture
 
             return capture.capture_trace(
@@ -221,7 +262,7 @@ def make_trace(
                 cpu_reuse=cpu_reuse, backend=backend)
         plan, edges, name = build_plan(app, graph_name, threads,
                                        num_kernels, windows_per_kernel, seed,
-                                       scale, cpu_reuse)
+                                       scale, cpu_reuse, **graph_kw)
         if backend == "jax":
             arrays = synth.synthesize(plan, seed, edges)
         elif backend == "ref":

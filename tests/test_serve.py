@@ -133,6 +133,23 @@ def test_malformed_spec_rejected_with_naming_error():
     assert "not-a-real-app" in resp.error
 
 
+def test_a_generated_graph_is_served_and_a_built_in_graph_refuses_its_keys():
+    kron = {"app": "bfs", "graph": "kronecker", "kron_scale": 10,
+            "edge_factor": 16, "graph_seed": 1, **SMALL}
+    srv = _server()
+    rid = srv.submit({"workloads": [kron], "mechanisms": ["cpu", "lazypim"]})
+    assert isinstance(rid, int)
+    (resp,) = srv.drain()
+    assert resp.status == OK and resp.rid == rid
+    (point,) = resp.results.points
+    assert point.workload == "bfs-kronecker"
+    assert set(point.results) == {"cpu", "lazypim"}
+    bad = dict(SPEC, workloads=[{**SPEC["workloads"][0], "graph_seed": 1}])
+    resp = srv.submit(bad)
+    assert resp.status == REJECTED_MALFORMED
+    assert "workloads[0]" in resp.error and "graph_seed" in resp.error
+
+
 def test_oversized_request_rejected_by_lane_bound():
     srv = _server(max_lanes=4)
     big = dict(SPEC, hw_grid={"offchip_bw_gbs": [float(b) for b in
