@@ -31,17 +31,20 @@ rollback replaying the entire kernel.
 **Packed hot path.**  All protocol state in the scan carry is packed uint32
 words (see ``repro.sim.prep``): five ``ceil(n/32)``-word line bitmaps plus
 two ``sig_bits/32``-word Bloom images, instead of the seed's five ``(n,)``
-and two ``(sig_bits,)`` boolean arrays.  Per window the step gathers each
-signature image against the static per-line hash-position table **once**
-(:func:`repro.sim.prep.line_sig_hits`) and derives every consumer from that
-gather: both conflict checks (:func:`repro.sim.prep.conflict_from_hits`
-fuses ``bank_bits_from_bitmap`` + ``conflict_any`` into a mod-16 segment
-reduction with no scatter) and all membership masks
-(:func:`repro.sim.prep.members_from_hits`).  The seed path materialized the
-16 × 2 Kbit bank twice per window and re-gathered per membership call.  The
-boolean seed implementation survives as
-:func:`repro.core._boolref.simulate_lazypim_bool` and the differential tests
-assert bit-exact ``SimResult`` equality.
+and two ``(sig_bits,)`` boolean arrays.  Before the scan the step lays the
+static per-line hash-position table out bit-major, ``(M, 32, words)``
+(:func:`repro.sim.prep.line_pos_bit_major`), so that bit ``b`` of a packed
+word sits on an axis of its own.  Per window the step looks both signature
+images up over that view and gets their hits already packed, one word per
+(segment, line word) (:func:`repro.sim.prep.sig_hit_words`).  Every
+consumer is word algebra on those words: the membership masks are the AND
+over segments (:func:`repro.sim.prep.hit_member_words`), and both conflict
+checks fold the masked hit words onto the 16 register bits
+(:func:`repro.sim.prep.conflict_from_hit_words`, ``bank_bits_from_bitmap``
++ ``conflict_any`` with no scatter).  The signature path makes no per-line
+boolean array and no relayout between lines and words.  The boolean seed
+implementation survives as :func:`repro.core._boolref.simulate_lazypim_bool`
+and the differential tests assert bit-exact ``SimResult`` equality.
 
 ``LazyPIMConfig`` is a registered pytree: numeric knobs (DBI interval and
 batch, commit exposure, the DBI enable) are traced data leaves, so sweeping
@@ -79,16 +82,17 @@ from repro.sim.prep import (
     XXH_PRIME2,
     XXH_PRIME5,
     TraceTensors,
-    conflict_from_hits,
+    conflict_from_hit_words,
     cpu_cache_step,
-    line_sig_hits,
+    hit_member_words,
+    line_pos_bit_major,
     line_window_u01,
-    members_from_hits,
     neutral_trace as prep_neutral,
     pack_bitmap,
     popcount_words,
     scatter_set,
     sig_bits_from_ids,
+    sig_hit_words,
 )
 
 __all__ = ["LazyPIMConfig", "simulate_lazypim"]
@@ -137,6 +141,7 @@ def _lazypim_acc(tt: TraceTensors, hw: HWParams, cfg: LazyPIMConfig):
     n = tt.num_lines
     sig_bytes_per_commit = 2.0 * tt.sig_bits / 8.0  # PIMReadSet + PIMWriteSet
     dbi_interval_ns = cfg.dbi_interval_cycles / hw.freq_ghz
+    pos_bm = line_pos_bit_major(tt)  # static per lane: built outside the scan
 
     def step(carry, w):
         carry_in = carry
@@ -180,24 +185,26 @@ def _lazypim_acc(tt: TraceTensors, hw: HWParams, cfg: LazyPIMConfig):
 
         # --- commit / conflict detection ------------------------------------
         commit = jnp.asarray(True) if cfg.partial_commits else tt.kernel_end[w]
-        # One lookup per signature image serves both conflict checks and all
-        # membership masks below.
-        rhits = line_sig_hits(tt, read_bits)    # (M, n)
-        c1 = conflict_from_hits(tt, cpuws, rhits, cfg.cpuws_regs) & commit
+        # One lookup per signature image; the hit words serve both conflict
+        # checks and all membership masks below.
+        rhits, whits = sig_hit_words(tt, pos_bm, read_bits, write_bits)
+        r_member = hit_member_words(rhits)
+        w_member = hit_member_words(whits)
+        c1 = conflict_from_hit_words(cpuws, rhits, cfg.cpuws_regs) & commit
         exact = jnp.any((cpuws & read_bm) != 0) & commit
 
         # Rollback path: flush dirty∩PIMReadSet (with FPs), replay; fresh
         # concurrent writes can conflict again; locked after max_rollbacks.
-        c2 = conflict_from_hits(tt, conc, rhits, cfg.cpuws_regs)
+        c2 = conflict_from_hit_words(conc, rhits, cfg.cpuws_regs)
         # A second conflict during the (shorter) re-execution adds one more
         # rollback; after max_rollbacks the conflicting lines are locked and
         # the commit is guaranteed (§5.5).
         rollbacks = jnp.where(c1, 1.0 + jnp.where(c2, 1.0, 0.0), 0.0)
 
         c1_mask = jnp.where(c1, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
-        flush_mask = members_from_hits(dirty, rhits) & c1_mask
+        flush_mask = dirty & r_member & c1_mask
         n_flush1 = popcount_words(flush_mask).astype(jnp.float32)
-        n_flush_conc = popcount_words(members_from_hits(conc, rhits)).astype(jnp.float32)
+        n_flush_conc = popcount_words(conc & r_member).astype(jnp.float32)
         n_flush = n_flush1 + jnp.maximum(rollbacks - 1.0, 0.0) * n_flush_conc
         dirty = dirty & ~flush_mask
 
@@ -209,11 +216,10 @@ def _lazypim_acc(tt: TraceTensors, hw: HWParams, cfg: LazyPIMConfig):
         rollback_ns = rollback_ns + flush_bytes / hw.offchip_bw_gbs
 
         # Successful commit: WAW merge + clean-line invalidation + drain.
-        whits = line_sig_hits(tt, write_bits)
         commit_mask = jnp.where(commit, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
-        merge_mask = members_from_hits(dirty, whits) & commit_mask
+        merge_mask = dirty & w_member & commit_mask
         n_merge = popcount_words(merge_mask).astype(jnp.float32)
-        inv_mask = members_from_hits(present, whits) & commit_mask
+        inv_mask = present & w_member & commit_mask
         present = present & ~inv_mask
         dirty = dirty & ~merge_mask
 

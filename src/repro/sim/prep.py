@@ -26,13 +26,16 @@ algebra (OR/AND/select/popcount) runs word-wise:
 * ``bank_bits_from_bitmap``— packed CPUWriteSet register bank
 * ``conflict_any``         — paper §5.3 AND-prefilter over segment-aligned
                              word masks
-* ``line_sig_hits``        — per-(segment, line) signature bit lookups; the
-                             shared lookup behind ``members`` and
-                             ``conflict_from_hits``
-* ``members``              — packed membership mask (with real H3 FPs)
-* ``conflict_from_hits``   — ``conflict_any∘bank_bits_from_bitmap`` fused
-                             into a lookup + mod-``R`` segment reduction
-                             (no scatter); bit-exact with the unfused pair
+* ``line_pos_bit_major``   — ``line_pos`` as (M, 32, words): bit ``b`` of
+                             word ``w`` on the axis of 32
+* ``sig_hit_words``        — per-(segment, word) signature hit words of
+                             packed images, looked up over that view
+* ``hit_member_words``     — membership words (with real H3 FPs): the AND
+                             of an image's hit words over segments
+* ``conflict_from_hit_words`` — ``conflict_any∘bank_bits_from_bitmap``
+                             fused into word ORs and a fold onto the ``R``
+                             register bits (no scatter, no unpack);
+                             bit-exact with the unfused pair
 * ``evict_to_cap``         — capacity eviction via word popcounts
 * ``cpu_cache_step``       — CPU-side presence/dirty word-bitmap evolution
 
@@ -262,11 +265,13 @@ def gather_hits(words: jax.Array, ids: jax.Array, valid: jax.Array) -> jax.Array
 
 
 def _ids_pos(tt: TraceTensors, ids: jax.Array) -> jax.Array:
-    """(M, A) signature positions of the line ids (A,): one 1-D gather per
-    segment row, so the compiler never re-lays the (M, num_lines) table
-    out with its short segment axis minor (32x padded on the TPU)."""
+    """(M, A) signature positions of the line ids (A,): one gather of
+    (segment, line) points.  A gather per segment row made the compiler
+    copy the whole (M, num_lines) table out into rows on every window, and
+    a gather of whole columns would lay the table out with its short
+    segment axis minor (32x padded on the TPU)."""
     idx = jnp.clip(ids, 0, tt.num_lines - 1)
-    return jnp.stack([tt.line_pos[m][idx] for m in range(tt.num_segments)])
+    return tt.line_pos[jnp.arange(tt.num_segments)[:, None], idx[None, :]]
 
 
 def sig_bits_from_ids(
@@ -293,7 +298,7 @@ def bank_bits_from_bitmap(
     dirty-line bitmap.  Register assignment is line_id % num_regs — the
     deterministic equivalent of the paper's round-robin pointer for
     set-valued (unordered) insertion.  The simulators use the fused
-    :func:`conflict_from_hits` instead of materializing the bank."""
+    :func:`conflict_from_hit_words` instead of materializing the bank."""
     bitmap = unpack_bitmap(words, tt.num_lines)
     bank = _bank_image_bool(tt, bitmap, num_regs)
     return jax.vmap(pack_bitmap)(bank)
@@ -309,63 +314,92 @@ def conflict_any(tt: TraceTensors, read_words: jax.Array, bank_words: jax.Array)
     return jnp.any(jnp.all(jnp.any(seg != 0, axis=2), axis=1))
 
 
-def line_sig_hits(tt: TraceTensors, sig_words: jax.Array) -> jax.Array:
-    """Per-(segment, line) signature bit lookups -> (M, num_lines) bool.
+def line_pos_bit_major(tt: TraceTensors) -> jax.Array:
+    """Bit-major view of ``line_pos``, (M, 32, num_line_words) int32:
+    ``[m, b, w]`` is the segment-``m`` position of line ``32 * w + b``.
 
-    One lookup of the packed image serves every consumer in a simulator
-    step: ``members`` is the all-segments AND, ``conflict_from_hits`` the
-    per-register segment OR — so the packed LazyPIM step looks each image
-    up once instead of once per membership/bank call.
+    Bit ``b`` of packed word ``w`` runs along the axis of 32, above the
+    words' axis, so a lookup over this view packs its hits by reducing
+    that axis, with the words on the TPU's lanes and no relayout.  Lines
+    past ``num_lines`` repeat the last line's positions; every consumer
+    ANDs the hit words with a bitmap whose pad bits are zero, so they
+    never count.  Built once per lane, outside the window scan."""
+    pos = tt.line_pos
+    pad = tt.num_line_words * 32 - tt.num_lines
+    pos = jnp.pad(pos, ((0, 0), (0, pad)), mode="edge")
+    return pos.reshape(tt.num_segments, -1, 32).transpose(0, 2, 1)
 
-    Lines run along the minor axis and the word lookup is a select chain
-    over the segment's ``words_per_seg`` words, not a gather: row ``m`` of
-    ``line_pos`` lies in segment ``m``.  A ``(num_lines, M)`` gather pads
-    its minor axis of 4 to the TPU's 128 lanes (32x the bytes) and runs
-    element by element."""
-    pos = tt.line_pos  # (M, n) int32 global positions, segment m in row m
+
+def _seg_word(local: jax.Array, seg_words: jax.Array) -> jax.Array:
+    """``seg_words[m, local[m, ...]]`` for (M, ...) word indices: a select
+    chain over the segment's words, not a gather (a gather from a table
+    this small runs element by element on the TPU)."""
+    bcast = (slice(None),) + (None,) * (local.ndim - 1)
+    w = jnp.zeros(local.shape, jnp.uint32)
+    for j in range(seg_words.shape[1]):
+        w = jnp.where(local == j, seg_words[:, j][bcast], w)
+    return w
+
+
+def sig_hit_words(
+    tt: TraceTensors, pos_bm: jax.Array, *images: jax.Array
+) -> tuple[jax.Array, ...]:
+    """Per-segment hit words of each packed signature image, one
+    (M, num_line_words) uint32 array per image: bit ``b`` of ``[m, w]`` is
+    set iff line ``32 * w + b``'s segment-``m`` hash bit is set in the image.
+
+    ``pos_bm`` is :func:`line_pos_bit_major`; the images share the word
+    index and bit shift computed from it.  Each image's hits pack by a sum
+    over the view's axis of 32: distinct bits, so the sum is exact.  Every
+    consumer is word algebra on the result: :func:`hit_member_words` and
+    :func:`conflict_from_hit_words`."""
     wps = tt.spec.words_per_seg
-    seg_words = sig_words.reshape(tt.num_segments, wps)
-    local = (pos >> 5) & (wps - 1)  # word within the segment (wps is pow2)
-    w = jnp.zeros(pos.shape, jnp.uint32)
-    for j in range(wps):
-        w = jnp.where(local == j, seg_words[:, j:j + 1], w)
-    return ((w >> (pos & 31).astype(jnp.uint32)) & 1) != 0
+    local = (pos_bm >> 5) & (wps - 1)  # word within the segment (wps is pow2)
+    shift = (pos_bm & 31).astype(jnp.uint32)
+    place = jnp.arange(32, dtype=jnp.uint32)[None, :, None]
+    out = []
+    for img in images:
+        word = _seg_word(local, img.reshape(tt.num_segments, wps))
+        bits = ((word >> shift) & np.uint32(1)) << place
+        out.append(jnp.sum(bits, axis=1, dtype=jnp.uint32))
+    return tuple(out)
 
 
-def members(tt: TraceTensors, words: jax.Array, sig_words: jax.Array) -> jax.Array:
-    """Packed per-line signature membership mask for lines set in ``words``.
-    Includes the signature's real false positives."""
-    return members_from_hits(words, line_sig_hits(tt, sig_words))
+def hit_member_words(hits: jax.Array) -> jax.Array:
+    """(num_line_words,) uint32: the lines whose hash bit is set in every
+    segment of the image :func:`sig_hit_words` looked up — signature
+    membership, real H3 false positives included.  AND it with a bitmap
+    to test that bitmap's lines."""
+    return jax.lax.reduce(hits, np.uint32(0xFFFFFFFF), jax.lax.bitwise_and, (0,))
 
 
-def members_from_hits(words: jax.Array, hits: jax.Array) -> jax.Array:
-    """``members`` given a precomputed :func:`line_sig_hits` lookup."""
-    return words & pack_bitmap(jnp.all(hits, axis=0))
-
-
-def conflict_from_hits(
-    tt: TraceTensors,
-    words: jax.Array,
-    hits: jax.Array,
-    num_regs: int = CPUWS_REGS,
+def conflict_from_hit_words(
+    words: jax.Array, hits: jax.Array, num_regs: int = CPUWS_REGS
 ) -> jax.Array:
     """``conflict_any(tt, sig, bank_bits_from_bitmap(tt, words))`` without
     building the bank: segment ``m`` of register ``r``'s intersection with
-    the read image is non-empty iff some line ``i ≡ r (mod num_regs)`` set
-    in ``words`` has its segment-``m`` hash bit set in the image — each
-    line's M positions land in M distinct segments, so the bank scatter
-    collapses to a lookup (``hits``) plus a mod-``num_regs`` any-reduction.
-    The reduction first folds rows of ``group`` lines (a multiple of
-    ``num_regs`` near the TPU's 128 lanes), so no axis as narrow as
-    ``num_regs`` is ever the minor one of a num_lines-sized array.
-    Bit-exact with the unfused pair (differentially tested)."""
-    n = tt.num_lines
-    masked = hits & unpack_bitmap(words, n)[None, :]  # (M, n)
-    group = num_regs * max(1, 128 // num_regs)
-    masked = jnp.pad(masked, ((0, 0), (0, (-n) % group)))
-    rows = jnp.any(masked.reshape(tt.num_segments, -1, group), axis=1)
-    seg_any = jnp.any(rows.reshape(tt.num_segments, -1, num_regs), axis=1)
-    return jnp.any(jnp.all(seg_any, axis=0))
+    the image is non-empty iff some line ``i ≡ r (mod num_regs)`` set in
+    ``words`` has its segment-``m`` hash bit set — each line's M positions
+    land in M distinct segments, so the bank scatter collapses to the
+    lookup ``hits`` (:func:`sig_hit_words`) plus a per-register OR.
+
+    ``num_regs`` divides 32, so bits ``r``, ``r + num_regs``, ... of every
+    word belong to register ``r``: OR the masked hit words of each segment
+    into one word, fold it onto its low ``num_regs`` bits, and a conflict
+    is a register bit left set in every segment.  O(M · num_line_words),
+    no unpack.  Bit-exact with the unfused pair (differentially tested)."""
+    if 32 % num_regs:
+        raise ValueError(f"num_regs={num_regs} must divide 32")
+    x = jax.lax.reduce(hits & words[None, :], np.uint32(0),
+                       jax.lax.bitwise_or, (1,))  # (M,)
+    width = 32
+    while width > num_regs:
+        width //= 2
+        x = x | (x >> np.uint32(width))
+    if num_regs < 32:
+        x = x & np.uint32((1 << num_regs) - 1)
+    all_segs = jax.lax.reduce(x, np.uint32(0xFFFFFFFF), jax.lax.bitwise_and, (0,))
+    return all_segs != 0
 
 
 def ids_member(

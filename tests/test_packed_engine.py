@@ -18,6 +18,7 @@ import pytest
 
 from repro.core import _boolref
 from repro.core.coherence import LazyPIMConfig, simulate_lazypim
+from repro.core.signatures import default_spec
 from repro.sim import prep as P
 from repro.sim.costmodel import HWParams
 from repro.sim.engine import (
@@ -134,23 +135,79 @@ def test_sig_and_bank_from_bitmap_match_bool(tt):
 
 
 def test_conflict_and_members_match_bool(tt):
+    pos_bm = P.line_pos_bit_major(tt)
     for seed in range(4):
         bm = _rand_bitmap(tt, seed, p=0.005 * (seed + 1))
         words = P.pack_bitmap(bm)
         img_b = P.sig_bits_from_ids_bool(tt, tt.pim_reads[seed],
                                          tt.pim_r_valid[seed])
+        wimg_b = P.sig_bits_from_ids_bool(tt, tt.pim_writes[seed],
+                                          tt.pim_w_valid[seed])
         img_p = P.pack_bitmap(img_b)
         c_bool = P.conflict_any_bool(tt, img_b, P.bank_bits_from_bitmap_bool(tt, bm))
         c_packed = P.conflict_any(tt, img_p, P.bank_bits_from_bitmap(tt, words))
-        hits = P.line_sig_hits(tt, img_p)
-        c_fused = P.conflict_from_hits(tt, words, hits)
+        hits, whits = P.sig_hit_words(tt, pos_bm, img_p, P.pack_bitmap(wimg_b))
+        assert hits.shape == whits.shape == (tt.num_segments, tt.num_line_words)
+        c_fused = P.conflict_from_hit_words(words, hits)
         assert bool(c_bool) == bool(c_packed) == bool(c_fused)
-        m_bool = P.members_bool(tt, bm, img_b)
-        m_packed = P.members(tt, words, img_p)
-        np.testing.assert_array_equal(np.asarray(P.pack_bitmap(m_bool)),
-                                      np.asarray(m_packed))
-        np.testing.assert_array_equal(np.asarray(m_packed),
-                                      np.asarray(P.members_from_hits(words, hits)))
+        for img, h in ((img_b, hits), (wimg_b, whits)):
+            np.testing.assert_array_equal(
+                np.asarray(P.pack_bitmap(P.members_bool(tt, bm, img))),
+                np.asarray(words & P.hit_member_words(h)))
+
+
+def _lines_trace(num_lines):
+    return P.dummy_trace(default_spec(), num_lines=num_lines, num_windows=1,
+                         num_kernels=1, pim_read_slots=1, pim_write_slots=1,
+                         cpu_read_slots=1, cpu_write_slots=1)
+
+
+@pytest.mark.parametrize("case", ["reg0", "reg15", "pad"])
+def test_conflict_word_fold(case):
+    """The word-fold conflict and membership against the boolean bank.
+
+    ``reg0``/``reg15``: lines of register r only, one at bit r of a word
+    and one at bit r + 16 of the next; the image holds the first line's
+    hash bits in the low half of the segments and the second's in the high
+    half, so only folding bit r + 16 onto register r sees every segment.
+    ``pad``: 1000 lines, so the last word has pad bits; the image holds the
+    last line's hash bits, which the pad lines repeat, and the bitmap every
+    line but the last — the pad lines' hits are set and must never count."""
+    tt = _lines_trace(1000 if case == "pad" else 4096)
+    n, m_segs = tt.num_lines, tt.num_segments
+    pos = np.asarray(tt.line_pos)
+    bm_sets = []
+    if case == "pad":
+        assert n % 32
+        img_pos = pos[:, n - 1]
+        bm_sets.append(np.arange(n - 1))
+    else:
+        r = 0 if case == "reg0" else 15
+        lo, hi = r, 32 + 16 + r  # bit r of word 0, bit r + 16 of word 1
+        half = m_segs // 2
+        img_pos = np.concatenate([pos[:half, lo], pos[half:, hi]])
+        bm_sets += [np.array([lo, hi]), np.array([lo]), np.array([hi])]
+    img_b = jnp.asarray(np.isin(np.arange(tt.sig_bits), img_pos))
+    img_p = P.pack_bitmap(img_b)
+    # The complement rides along as a second image of the same lookup.
+    hits, not_hits = P.sig_hit_words(tt, P.line_pos_bit_major(tt), img_p, ~img_p)
+    got = []
+    for lines in bm_sets:
+        bm = jnp.asarray(np.isin(np.arange(n), lines))
+        words = P.pack_bitmap(bm)
+        want = bool(P.conflict_any_bool(
+            tt, img_b, P.bank_bits_from_bitmap_bool(tt, bm)))
+        assert bool(P.conflict_from_hit_words(words, hits)) == want
+        got.append(want)
+        for img, h in ((img_b, hits), (~img_b, not_hits)):
+            np.testing.assert_array_equal(
+                np.asarray(P.pack_bitmap(P.members_bool(tt, bm, img))),
+                np.asarray(words & P.hit_member_words(h)))
+    if case == "pad":
+        tail = np.asarray(hits)[:, -1] >> np.uint32(n % 32)
+        assert (tail != 0).all() and got == [False]
+    else:
+        assert got[0]  # both halves together cover every segment
 
 
 def test_evict_to_cap_matches_bool(tt):
