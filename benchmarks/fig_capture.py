@@ -19,8 +19,8 @@ streams), the arithmetic-intensity profiles
 ``benchmarks/check_budget.py`` gates in CI.
 
 ``--smoke`` runs the CI-sized leg: a tiny capture (2 decode steps), a
-validity + determinism assert, and one Study point through ``run_batch``
-with plan == measured compiles — no JSON is written.
+validity + determinism assert, and one Study point through the batched
+planner with plan == measured compiles — no JSON is written.
 """
 
 from __future__ import annotations
@@ -90,7 +90,7 @@ def run(threads: int = 16) -> dict:
 
 def smoke() -> None:
     """CI capture smoke: tiny config, 2 decode steps, one Study point
-    through run_batch, plan == measured."""
+    through the batched planner, plan == measured."""
     import numpy as np
 
     from repro.sim.prep import bucket_bound, prepare

@@ -232,12 +232,13 @@ def phase_served(specs=SERVED_SPECS) -> dict:
 
 def shard_placement(study, devices: int) -> dict:
     """Where the output shards of one sharded dispatch live: the stacked
-    accumulator of the ``cpu`` scan over the study's single bucket."""
+    accumulator of the ``cpu`` scan over the study's single bucket, its
+    lanes stacked as the planner stacks them."""
     from repro.sim import engine
 
     (bl,) = study.bucket_lanes()
-    stt = engine.neutral_trace(engine.stack_traces(bl.traces))
-    acc = engine._sweep_fn_mesh("cpu", devices)(stt, engine.stack_hw(bl.hws))
+    stt, shw, _ = study._stack_lanes(bl, len(bl.traces), devices)
+    acc = engine._sweep_fn_mesh("cpu", devices)(stt, shw)
     leaf = next(iter(acc.values()))
     return {"sharding": str(leaf.sharding),
             "shards": [{"device": str(s.device), "lanes": s.data.shape[0]}

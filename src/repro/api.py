@@ -14,9 +14,8 @@ Everything here re-exports from the simulation stack:
   results and the predicted compile budget.
 * :class:`HWParams`, :class:`LazyPIMConfig`, :class:`SignatureSpec` — the
   hardware / protocol / signature parameter spaces.
-* The layered engines (:func:`run_all`, :func:`run_sweep`,
-  :func:`run_batch`, :func:`summarize`) for code that wants the
-  lower-level entry points the planner dispatches through.
+* The sequential reference engine (:func:`run_all`) and
+  :func:`summarize`, for code that runs one prepared trace per point.
 """
 
 from repro.core.coherence import LazyPIMConfig
@@ -26,9 +25,6 @@ from repro.sim.costmodel import HWParams
 from repro.sim.engine import (
     MECHANISMS,
     run_all,
-    run_batch,
-    run_sweep,
-    run_workload,
     summarize,
     sweep_cache_sizes,
 )
@@ -51,6 +47,6 @@ __all__ = [
     "Workload", "workload", "HWGrid", "grid", "Dispatch",
     "HWParams", "LazyPIMConfig", "SignatureSpec",
     "SimResult", "TraceTensors", "MECHANISMS",
-    "run_all", "run_batch", "run_sweep", "run_workload", "summarize",
+    "run_all", "summarize",
     "sweep_cache_sizes", "prepare", "make_trace", "all_workloads",
 ]

@@ -16,8 +16,8 @@ gathers and scatters, never changing the model math — and emits a
   boundaries marked.
 
 Captured traces are first-class workloads: ``make_trace(app="capture/
-kv_serve")`` (and friends) routes here, so they flow through ``Study``,
-``run_batch``, and serve coalescing unchanged.  Every random decision is
+kv_serve")`` (and friends) routes here, so they flow through ``Study``
+(sequential and batched) and serve coalescing unchanged.  Every random decision is
 counter-PRNG keyed on (model seed, request-mix seed) — the same seed
 gives a bit-identical ``WindowTrace``.
 """

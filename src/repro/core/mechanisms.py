@@ -17,8 +17,8 @@ Five mechanisms share one window-granular execution model (see
 The simulators run on the **packed word path** of ``repro.sim.prep``: every
 per-line bitmap in the scan carry is a ``ceil(num_lines/32)`` uint32 array,
 and ``HWParams`` is a traced pytree — one compiled step function serves
-every hardware point (``repro.sim.engine.run_sweep`` vmaps it over stacked
-sweep axes).  The boolean seed implementations live in
+every hardware point (``repro.sim.engine._sweep_fn`` vmaps it over the
+stacked lane axis).  The boolean seed implementations live in
 ``repro.core._boolref`` and are asserted bit-exact by
 ``tests/test_packed_engine.py``.
 
@@ -180,7 +180,7 @@ class ResultIntegrityError(ValueError):
 
 def finalize_result(name: str, mechanism: str, acc: dict) -> SimResult:
     """THE accumulator→``SimResult`` constructor: every engine (sequential
-    simulators, ``run_sweep``, the batch/study planner) funnels its raw
+    simulators, the batched ``Study`` planner) funnels its raw
     accumulator dict through here, so result construction cannot drift
     between engines (the bit-exact cross-engine tests pin it).  Every
     value passes the NaN/Inf/negative integrity sentinel
@@ -482,7 +482,7 @@ def simulate_nc(tt: TraceTensors, hw: HWParams) -> SimResult:
 
 
 # Unjitted window-scan accumulators, keyed by mechanism name — the raw
-# step functions ``repro.sim.engine.run_sweep`` vmaps over stacked
+# step functions ``repro.sim.engine._sweep_fn`` vmaps over stacked
 # trace/hardware axes (LazyPIM registers itself in ``repro.core.coherence``).
 ACC_FNS = {
     "cpu": _cpu_only_acc,

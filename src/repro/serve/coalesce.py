@@ -5,10 +5,10 @@ kernels that actually conflict; the serve layer treats queued requests the
 same way.  Compatible admitted studies — same geometry bucket, same
 signature spec, same mechanism set, same static lazy flags, i.e. the same
 *compile key context* — stack their per-lane (trace, hw, lazy) triples
-into ONE batched engine dispatch, padded up to a small set of **blessed
-pow2 lane widths** with all-sentinel masked lanes
-(:func:`repro.serve.warm.dummy_trace`), and the stacked accumulators split
-back per request by lane slice
+into ONE batched engine dispatch through the engine's one stacking seam
+(:func:`repro.sim.engine.stack_lanes`), padded up to a small set of
+**blessed pow2 lane widths** with all-sentinel masked lanes, and the
+stacked accumulators split back per request by lane slice
 (:meth:`repro.sim.study.Study.points_from_lane_accs`).
 
 Blessed widths are the whole compile-cost story: without them, every
@@ -35,10 +35,10 @@ from repro.core.coherence import LazyPIMConfig
 from repro.core.signatures import SignatureSpec
 from repro.sim import engine as _engine
 from repro.sim.costmodel import HWParams
-from repro.sim.prep import TraceTensors, bucket_shapes, neutral_trace
+from repro.sim.prep import TraceTensors, bucket_shapes
 from repro.sim.study import Study
 from repro.sim.synth import threefry2x32
-from repro.serve.warm import _GEOMETRY_KEYS, dummy_trace
+from repro.serve.warm import _GEOMETRY_KEYS
 
 __all__ = [
     "BLESSED_LANE_WIDTHS", "GroupKey", "LaneSlice", "blessed_width",
@@ -120,8 +120,7 @@ def group_key(study: Study) -> GroupKey | None:
         shape=tuple(sorted(shape.items())),
         spec=tts[idx[0]].spec,
         mechanisms=study.mechanisms,
-        lazy_static=tuple((f, getattr(lazy0, f))
-                          for f in _engine._LAZY_STATIC_FIELDS))
+        lazy_static=tuple(_engine.lazy_static(lazy0).items()))
 
 
 def group_lanes(
@@ -148,23 +147,14 @@ def group_lanes(
 def stack_group(key: GroupKey, members: list[tuple[int, Study]],
                 devices: int = 1):
     """Build the stacked (trace, hw, lazy) pytrees for one coalesced
-    dispatch: member lanes in member order, padded with all-sentinel
-    masked lanes (:func:`repro.sim.prep.dummy_trace` — zero contribution
-    by the window-validity masking) up to the blessed width (the smallest
+    dispatch: member lanes in member order, stacked by
+    :func:`repro.sim.engine.stack_lanes` at the blessed width (the smallest
     one a ``devices``-wide lane mesh divides).  Returns
     ``(stt, shw, scfg, slices, width)``."""
     traces, hws, lazys, slices = group_lanes(members)
     width = blessed_width(len(traces), devices)
-    pad = width - len(traces)
-    if pad:
-        shape = dict(key.shape)
-        dt = dummy_trace(key.spec, **shape)
-        traces = traces + [dt] * pad
-        hws = hws + [HWParams()] * pad
-        lazys = lazys + [LazyPIMConfig(**dict(key.lazy_static))] * pad
-    stt = neutral_trace(_engine.stack_traces(traces))
-    shw = _engine.stack_hw(hws)
-    scfg = _engine.stack_lazy(lazys)
+    stt, shw, scfg = _engine.stack_lanes(traces, hws, lazys, dict(key.shape),
+                                         width)
     return stt, shw, scfg, slices, width
 
 

@@ -34,7 +34,6 @@ reads.
 
 from __future__ import annotations
 
-import dataclasses
 import json
 import pathlib
 import shutil
@@ -42,15 +41,11 @@ import shutil
 import jax
 import jax.numpy as jnp
 
-from repro.core.coherence import LazyPIMConfig
 from repro.core.signatures import SignatureSpec
 from repro.runtime.compile_cache import CHECKOUT
 from repro.sim import engine as _engine
 from repro.sim import mesh as _mesh
-from repro.sim.costmodel import HWParams
-from repro.sim.prep import bucket_shapes, dummy_trace  # noqa: F401  (dummy_
-#   trace moved to prep — the canonical home shared with the coalescer and
-#   the planner's mesh pads — and is re-exported here for compatibility)
+from repro.sim.prep import dummy_lane_triple
 from repro.sim.study import Study
 
 MANIFEST_NAME = "warm_manifest.json"
@@ -85,34 +80,22 @@ def fresh_serve_dir(name: str) -> pathlib.Path:
 
 
 def study_warm_entries(study: Study, devices: int = 1) -> list[dict]:
-    """The planner tuples a study's batched execution compiles: one entry
-    per (mechanism, geometry bucket) with the stacked lane count, the
-    lane-mesh routing (``devices``, with the lane count padded to the mesh
-    multiple the dispatch actually compiled at) and the static compile-key
-    context (signature spec, static lazy flags).  JSON-able — this is the
-    manifest row format."""
-    tts = study.traces()
-    lanes = study._lanes()
-    lazy0 = study.lazy_points()[0]
-    static = {f: getattr(lazy0, f) for f in _engine._LAZY_STATIC_FIELDS}
-    entries = []
-    for idx, shape in bucket_shapes(tts):
-        members = set(idx)
-        n_lanes = sum(1 for lane in lanes if lane[0] in members)
-        if not n_lanes:
-            continue
-        d = _mesh.devices_for(n_lanes, devices)
-        spec = tts[idx[0]].spec
-        for m in study.mechanisms:
-            entries.append({
-                **{k: int(shape[k]) for k in _GEOMETRY_KEYS},
-                "mechanism": m,
-                "lanes": int(_mesh.mesh_lane_width(n_lanes, d)),
-                "devices": int(d),
-                "spec": dataclasses.asdict(spec),
-                "lazy_static": dict(static),
-            })
-    return entries
+    """The planner tuples a study's batched execution compiles, read from
+    :meth:`Study.plan`: one entry per (mechanism, geometry bucket) with the
+    bucket's geometry, its padded lane count and lane-mesh size (the width
+    the dispatch actually compiled at) and the static compile-key context
+    (signature spec, static lazy flags).  JSON-able — this is the manifest
+    row format."""
+    static = _engine.lazy_static(study.lazy_points()[0])
+    return [{
+        **{k: int(b[k]) for k in _GEOMETRY_KEYS},
+        "mechanism": m,
+        "lanes": int(b["padded_lanes"]),
+        "devices": int(b["devices"]),
+        "spec": dict(b["spec"]),
+        "lazy_static": dict(static),
+    } for b in study.plan(devices).buckets if b["lanes"]
+        for m in study.mechanisms]
 
 
 def _entry_key(e: dict) -> str:
@@ -122,17 +105,17 @@ def _entry_key(e: dict) -> str:
 def dummy_stacked(entry: dict):
     """Build the (stacked trace, stacked hw, stacked lazy) triple whose jit
     key equals a manifest entry's compile key: exact bucket geometry and
-    lane count, every lane the all-sentinel :func:`dummy_trace`.  Built
-    from jnp ops only, so ``jax.eval_shape`` gives the entry's argument
-    shapes without materializing any lane (``tests/test_tpu_compile.py``)."""
-    tt = dummy_trace(SignatureSpec(**entry["spec"]),
-                     **{k: entry[k] for k in _GEOMETRY_KEYS})
+    lane count, every lane the pad triple of
+    :func:`repro.sim.prep.dummy_lane_triple`.  The trace is broadcast with
+    jnp ops, so ``jax.eval_shape`` gives the entry's argument shapes
+    without materializing any lane (``tests/test_tpu_compile.py``)."""
+    tt, hw, cfg = dummy_lane_triple(
+        SignatureSpec(**entry["spec"]),
+        {k: entry[k] for k in _GEOMETRY_KEYS}, entry["lazy_static"])
     lanes = entry["lanes"]
     stt = jax.tree.map(lambda x: jnp.broadcast_to(x, (lanes, *x.shape)), tt)
-    shw = _engine.stack_hw([HWParams()] * lanes)
-    scfg = _engine.stack_lazy(
-        [LazyPIMConfig(**entry["lazy_static"])] * lanes)
-    return stt, shw, scfg
+    return (stt, _engine.stack_hw([hw] * lanes),
+            _engine.stack_lazy([cfg] * lanes))
 
 
 class WarmCache:

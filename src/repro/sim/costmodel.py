@@ -56,8 +56,9 @@ class HWParams:
     leaf: no field determines an array shape or Python-level control flow
     (core counts, latencies, bandwidths, energies, and cache caps all enter
     the cost model arithmetically), so a single compiled simulator step
-    serves every HWParams point and :func:`repro.sim.engine.run_sweep` can
-    ``vmap`` one step function over stacked parameter axes instead of
+    serves every HWParams point and the batched engine
+    (:func:`repro.sim.engine._sweep_fn`) can ``vmap`` one step function
+    over stacked parameter axes instead of
     recompiling per sweep point (the seed passed HWParams via
     ``static_argnums``, paying one XLA compile per distinct value).
     """

@@ -3,36 +3,30 @@
 The one front door for experiments is :class:`repro.sim.study.Study`
 (re-exported as ``repro.api``): a declarative (workloads × hw × mechanisms ×
 lazy-config) spec whose ``run()`` plans execution automatically.  This
-module provides the layered engines the planner dispatches through — kept
-public because they are also the differential references that pin the
-planner bit-exact:
+module holds the two engines the planner dispatches through:
 
 * **Sequential reference** — :func:`run_all` / :func:`run_mechanism` run one
   prepared trace through each mechanism's own jitted scan
   (``neutral_trace`` keys the jit cache on geometry, not workload name).
-  This is the readable per-point path every batched engine is tested
-  against, field-for-field.
-* **Stacked dispatch** — :func:`run_sweep` executes a *pre-stacked* sweep:
-  every tensor leaf of the trace / hardware / lazy-config pytrees carries a
-  leading point axis (:func:`stack_traces` / :func:`stack_hw` /
-  :func:`stack_lazy`), and one jitted+vmapped scan per mechanism
+  This is the readable per-point path the batched engine is tested
+  against, field-for-field (``Study.run(engine="sequential")``).
+* **Stacked dispatch** — :func:`stack_lanes` is the one seam through which
+  every batched caller (the ``Study`` planner, the serve layer's
+  cross-request coalescer, the chip smoke) turns a geometry bucket's
+  per-lane (trace, hw, lazy) triples into one dispatch: it pads the lanes
+  to a width with :func:`repro.sim.prep.dummy_lane_triple` and stacks
+  them into pytrees whose tensor leaves carry a leading lane axis
+  (:func:`stack_traces` / :func:`stack_hw` / :func:`stack_lazy`).
+  :func:`_sweep_accs` then runs one jitted+vmapped scan per mechanism
   (:func:`_sweep_fn`, lru-cached — its jit cache size IS the measured
-  compile count, :func:`sweep_cache_sizes`) runs all points in one
-  execution.  ``HWParams`` leaves and ``LazyPIMConfig``'s numeric knobs are
-  traced, so any values ride one compile; only trace geometry,
-  ``SignatureSpec`` and the static lazy flags (``partial_commits``,
-  ``cpuws_regs``, ``max_rollbacks``) select a different compiled function.
-* **Bucketed fleet** — :func:`run_batch` is the planner's fleet form: a
-  mixed-geometry workload list is grouped into pow2-ish geometry buckets
-  (:func:`repro.sim.prep.bucket_traces`), padded under explicit validity
-  masks, and dispatched through the stacked engine — one XLA compile per
-  (mechanism, bucket) for any fleet size, bit-exact with sequential
-  :func:`run_all` on every ``SimResult`` field.  ``run_batch`` itself is a
-  thin wrapper over the ``Study`` planner, so the long-standing
-  differential/golden tests (``tests/test_batch_engine.py``,
-  ``tests/golden/fig7_batched_golden.json``) pin the planner's numerics.
+  compile count, :func:`sweep_cache_sizes`) over all lanes in one
+  execution.  ``HWParams`` leaves and ``LazyPIMConfig``'s numeric knobs
+  are traced, so any values ride one compile; only trace geometry,
+  ``SignatureSpec``, the lane count and the static lazy flags
+  (``partial_commits``, ``cpuws_regs``, ``max_rollbacks``) select a
+  different compiled function.
 
-The planner composes the axes by *folding them into the stacked workload
+The planner composes the axes by *folding them into the stacked lane
 axis*: an hw grid or lazy ablation repeats each padded trace per
 (hw-point, lazy-point) lane, so the whole cross-product still costs at most
 one compile per (mechanism, bucket, static-flag combo) —
@@ -54,23 +48,20 @@ from repro.core.coherence import LazyPIMConfig, _lazypim_acc, simulate_lazypim
 from repro.core.mechanisms import (
     ACC_FNS,
     SimResult,
-    _finalize,
     simulate_cg,
     simulate_cpu_only,
     simulate_fg,
     simulate_ideal,
     simulate_nc,
 )
-from repro.core.signatures import SignatureSpec
 from repro.runtime import spans
 from repro.sim.costmodel import HWParams, hw_leaf_dtypes
 from repro.sim.prep import (
     TRACE_DATA_FIELDS,
     TraceTensors,
+    dummy_lane_triple,
     neutral_trace,
-    prepare,
 )
-from repro.sim.trace import make_trace
 
 MECHANISMS = ("cpu", "fg", "cg", "nc", "lazypim", "ideal")
 
@@ -105,7 +96,7 @@ def run_all(
 
 
 # ---------------------------------------------------------------------------
-# Pytree stacking: the leading point axis of the stacked dispatch engine
+# Pytree stacking: the leading lane axis of the stacked dispatch engine
 # ---------------------------------------------------------------------------
 
 
@@ -135,6 +126,12 @@ _LAZY_DATA_DTYPES = {
 _LAZY_STATIC_FIELDS = ("partial_commits", "cpuws_regs", "max_rollbacks")
 
 
+def lazy_static(cfg: LazyPIMConfig) -> dict:
+    """A lazy config's static flags, which select the compiled dataflow:
+    part of a dispatch's compile key, like the trace geometry."""
+    return {f: getattr(cfg, f) for f in _LAZY_STATIC_FIELDS}
+
+
 def stack_lazy(cfgs: list[LazyPIMConfig]) -> LazyPIMConfig:
     """Stack LazyPIMConfigs into one pytree with (S,)-shaped numeric leaves.
 
@@ -152,7 +149,7 @@ def stack_lazy(cfgs: list[LazyPIMConfig]) -> LazyPIMConfig:
                     f"{getattr(c0, f)!r} of config [0]: static flags select "
                     f"a different compiled dataflow and cannot share one "
                     f"stacked sweep")
-    kw = {f: getattr(c0, f) for f in _LAZY_STATIC_FIELDS}
+    kw = lazy_static(c0)
     for name, dt in _LAZY_DATA_DTYPES.items():
         kw[name] = spans.h2d(np.asarray(
             [getattr(c, name) for c in cfgs], dtype=np.dtype(dt)))
@@ -166,9 +163,9 @@ def stack_traces(tts: list[TraceTensors]) -> TraceTensors:
     All traces must share geometry metadata (line/window/kernel counts,
     access-slot widths and signature spec — they select the compiled
     shapes); raw mismatched-geometry stacks are rejected with a
-    ``ValueError`` — route mixed fleets through :func:`run_batch` or a
-    ``Study``, whose bucketing layer (:func:`repro.sim.prep.bucket_traces`)
-    pads them onto shared bucket shapes first.  ``name``/``threads`` are
+    ``ValueError`` — route mixed fleets through a ``Study``, whose
+    bucketing layer (:func:`repro.sim.prep.bucket_shapes`) pads them onto
+    shared bucket shapes first.  ``name``/``threads`` are
     taken from the first trace; the locality constants (``cpu_reuse``,
     ``cpu_priv_miss_rate``) are traced scalar leaves and stack per point
     like every other tensor.
@@ -182,7 +179,7 @@ def stack_traces(tts: list[TraceTensors]) -> TraceTensors:
                                   "cpu_reads", "cpu_writes")))
         if not same:
             raise ValueError(f"cannot stack {t.name}: geometry differs from "
-                             f"{t0.name} (run_batch buckets mixed fleets)")
+                             f"{t0.name} (a Study buckets mixed fleets)")
     fields = {f.name: getattr(t0, f.name) for f in dataclasses.fields(t0)}
     for key in TRACE_DATA_FIELDS:
         # Host-side stack + one device put per field: jnp.stack on a list
@@ -191,6 +188,33 @@ def stack_traces(tts: list[TraceTensors]) -> TraceTensors:
         fields[key] = spans.h2d(
             np.stack([spans.d2h(getattr(t, key)) for t in tts]))
     return TraceTensors(**fields)
+
+
+def stack_lanes(
+    traces: list[TraceTensors],
+    hws: list[HWParams],
+    lazys: list[LazyPIMConfig],
+    shape: dict[str, int],
+    width: int,
+) -> tuple[TraceTensors, HWParams, LazyPIMConfig]:
+    """One geometry bucket's lanes as the stacked (trace, hw, lazy) pytrees
+    of one dispatch: the lanes, all padded to the bucket ``shape`` (the
+    ``pad_trace`` kwargs), in order, then ``width - len(traces)`` pad lanes
+    (:func:`repro.sim.prep.dummy_lane_triple`: all-sentinel masked traces
+    that contribute nothing, carrying lane 0's static lazy flags).  The
+    ``width`` is the caller's compile-key policy — the planner's mesh
+    multiple, the coalescer's blessed width.  Every batched caller stacks
+    through here, so a change to how lanes become one dispatch is a change
+    to this function."""
+    pad = width - len(traces)
+    if pad:
+        tt, hw, cfg = dummy_lane_triple(traces[0].spec, shape,
+                                        lazy_static(lazys[0]))
+        traces = traces + [tt] * pad
+        hws = hws + [hw] * pad
+        lazys = lazys + [cfg] * pad
+    return (neutral_trace(stack_traces(traces)), stack_hw(hws),
+            stack_lazy(lazys))
 
 
 # ---------------------------------------------------------------------------
@@ -277,9 +301,9 @@ def _sweep_fn_mesh(mechanism: str, devices: int = 1):
 def sweep_cache_sizes(mechanisms: tuple[str, ...] = MECHANISMS) -> dict[str, int]:
     """Measured XLA compile count per mechanism's sweep function (0 if the
     sweep function has never run), summed over the single-device function
-    and every mesh variant built in this process.  Every batched engine —
-    ``run_sweep``, ``run_batch``, the ``Study`` planner, sharded or not —
-    executes through these functions, so the delta of these counts across a
+    and every mesh variant built in this process.  Every batched dispatch —
+    the ``Study`` planner's and the serve layer's coalesced ones, sharded or
+    not — executes through these functions, so the delta of these counts across a
     run is that run's measured compile cost (cross-checked against
     ``Study.plan()`` by ``benchmarks/check_budget.py --live``)."""
     return {m: _sweep_fn(m)._cache_size()
@@ -312,9 +336,9 @@ def _sweep_accs(
     devices: int = 1,
 ) -> dict[str, dict]:
     """Dispatch one stacked execution per mechanism; return host-side
-    accumulator dicts with a leading point axis.  THE shared dispatch of
-    every batched engine: ``run_sweep`` finalizes its output per point, the
-    ``Study`` planner per (bucket, lane).
+    accumulator dicts with a leading lane axis.  THE shared dispatch of
+    every batched caller, over the pytrees :func:`stack_lanes` built; the
+    ``Study`` finalizes its output per (bucket, lane).
 
     ``boundary`` is the per-dispatch error/cancellation boundary: a callable
     ``(mechanism, thunk) -> accs`` invoked once per mechanism with a
@@ -327,8 +351,8 @@ def _sweep_accs(
 
     ``devices`` selects the mesh variant: the stacked lane axis shards over
     a ``devices``-wide lane mesh (the lane count must already be a multiple
-    of ``devices`` — the planner pads with :func:`repro.sim.prep.dummy_trace`
-    lanes).  ``devices=1`` is the byte-identical single-device path.
+    of ``devices`` — :func:`stack_lanes` pads to the width the caller
+    asks for).  ``devices=1`` is the byte-identical single-device path.
 
     Each dispatch is a ``repro:scan:<mechanism>`` span in a profiler trace
     (the call and the read of its accumulators, ``d2h_bytes``, ``lanes``);
@@ -346,74 +370,6 @@ def _sweep_accs(
 
         out[m] = thunk() if boundary is None else boundary(m, thunk)
     return out
-
-
-def run_sweep(
-    tt: TraceTensors,
-    hw: HWParams,
-    mechanisms: tuple[str, ...] = MECHANISMS,
-    lazy_cfg: LazyPIMConfig | None = None,
-) -> list[dict[str, SimResult]]:
-    """Run every mechanism over a stacked sweep in one batched execution.
-
-    ``tt``/``hw`` carry a leading sweep axis S on every tensor leaf (from
-    :func:`stack_traces` / :func:`stack_hw`; a single trace can be tiled via
-    ``stack_traces([tt] * S)``).  ``lazy_cfg`` is one config applied to
-    every point (its leaves are broadcast onto the sweep axis; pass a
-    per-point lazy axis through a ``Study`` instead).  Returns one
-    ``{mechanism: SimResult}`` dict per sweep point — the same values,
-    bit-for-bit, as S sequential :func:`run_all` calls (differentially
-    tested), but compiled once per mechanism regardless of S.
-    """
-    if not mechanisms:
-        return []
-    lazy_cfg = lazy_cfg or LazyPIMConfig()
-    num_points = jax.tree_util.tree_leaves(hw)[0].shape[0]
-    ntt = neutral_trace(tt)  # jit keys on geometry, not the workload name
-    scfg = stack_lazy([lazy_cfg] * num_points)
-    accs = _sweep_accs(ntt, hw, mechanisms, scfg)
-    points: list[dict[str, SimResult]] = []
-    for i in range(num_points):
-        points.append({
-            m: _finalize(tt, m, {k: v[i] for k, v in acc.items()})
-            for m, acc in accs.items()
-        })
-    return points
-
-
-# ---------------------------------------------------------------------------
-# Geometry-bucketed fleet batch engine (a thin wrapper over the planner)
-# ---------------------------------------------------------------------------
-
-
-def run_batch(
-    tts: list[TraceTensors],
-    hw: HWParams | list[HWParams] | None = None,
-    mechanisms: tuple[str, ...] = MECHANISMS,
-    lazy_cfg: LazyPIMConfig | None = None,
-) -> list[dict[str, SimResult]]:
-    """Run a whole workload fleet with one compiled scan per (mechanism,
-    geometry bucket).
-
-    Thin wrapper over the ``Study`` planner (:mod:`repro.sim.study`): the
-    fleet becomes a study over prepared traces, ``hw`` one HWParams applied
-    fleet-wide or a list aligned with ``tts`` (one per workload — the hook
-    that composes an hw axis with the workload axis), and results come back
-    per input workload, in input order — bit-exact with sequential
-    :func:`run_all` on every ``SimResult`` field (differentially tested in
-    ``tests/test_batch_engine.py``), at most ``len(mechanisms) ×
-    num_buckets`` measured compiles for any fleet size.
-    """
-    from repro.sim.study import Study
-
-    if not tts:
-        return []
-    if hw is not None and not isinstance(hw, HWParams):
-        hw = list(hw)
-        if len(hw) != len(tts):
-            raise ValueError(f"hw list length {len(hw)} != fleet size {len(tts)}")
-    study = Study(workloads=tts, hw=hw, mechanisms=mechanisms, lazy=lazy_cfg)
-    return [p.results for p in study.run().points]
 
 
 def summarize(results: dict[str, SimResult], hw: HWParams,
@@ -437,25 +393,3 @@ def summarize(results: dict[str, SimResult], hw: HWParams,
             blocked_accesses=r.blocked_accesses,
         )
     return out
-
-
-def run_workload(
-    app: str,
-    graph_name: str | None = None,
-    threads: int = 16,
-    hw: HWParams | None = None,
-    spec: SignatureSpec | None = None,
-    mechanisms: tuple[str, ...] = MECHANISMS,
-    lazy_cfg: LazyPIMConfig | None = None,
-    **trace_kw,
-) -> dict[str, SimResult]:
-    """Convenience: trace -> prepare -> run_all (any workload family —
-    seed graph/HTAP or the extended frontier/streaming/multi-tenant apps).
-
-    With ``spec=None``, ``prepare`` applies the shared
-    :func:`repro.core.signatures.default_spec` singleton — one set of
-    byte-sliced H3 tables, one jit cache entry per mechanism — instead of
-    re-deriving the hash family per call."""
-    trace = make_trace(app, graph_name, threads=threads, **trace_kw)
-    tt = prepare(trace, spec)
-    return run_all(tt, hw or HWParams(), mechanisms, lazy_cfg)

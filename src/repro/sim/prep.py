@@ -55,8 +55,9 @@ geometry: :func:`bucket_bound` rounds line counts up pow2-ish,
 validity (padded lines never enter a bitmap or signature, padded windows
 are marked in ``window_valid`` and leave every scan carry untouched), and
 :func:`bucket_traces` groups a fleet into those buckets —
-``repro.sim.engine.run_batch`` vmaps one compiled scan per (mechanism,
-bucket) over the stacked workload axis, bit-exact with the sequential path.
+the ``Study`` planner (``repro.sim.study``) vmaps one compiled scan per
+(mechanism, bucket) over the stacked lane axis, bit-exact with the
+sequential path.
 """
 
 from __future__ import annotations
@@ -100,7 +101,7 @@ def line_window_u01(
 # truth for both the pytree registration and engine.stack_traces.
 # ``cpu_priv_miss_rate``/``cpu_reuse`` are *traced* scalar leaves (not
 # static): workloads that differ only in their locality constants share one
-# compiled step and can ride in one geometry bucket (engine.run_batch).
+# compiled step and can ride in one geometry bucket (the Study planner).
 TRACE_META_FIELDS = ("name", "threads", "num_lines", "num_windows",
                      "num_kernels", "spec")
 TRACE_DATA_FIELDS = ("line_pos", "line_reg", "pim_reads", "pim_writes",
@@ -842,8 +843,9 @@ def dummy_lane_triple(spec: SignatureSpec, shape: dict[str, int],
     ``pad_trace`` kwargs): the all-sentinel :func:`dummy_trace`, default
     ``HWParams``, and a default lazy config carrying the group's static
     flags (static flags are compile-key context and must match the real
-    lanes they pad).  The shared pad-lane recipe of the coalescer's
-    blessed-width padding and the mesh planner's lane padding."""
+    lanes they pad).  The one pad-lane recipe: ``engine.stack_lanes``
+    pads every batched dispatch with it (the planner's mesh multiple, the
+    coalescer's blessed width), and the warm replay broadcasts it."""
     from repro.core.coherence import LazyPIMConfig
 
     return (dummy_trace(spec, **shape), HWParams(),

@@ -67,8 +67,7 @@ from repro.runtime import spans
 from repro.sim import engine as _engine
 from repro.sim import mesh as _mesh
 from repro.sim.costmodel import HWParams
-from repro.sim.prep import (TraceTensors, bucket_shapes, dummy_lane_triple,
-                            pad_trace, prepare)
+from repro.sim.prep import TraceTensors, bucket_shapes, pad_trace, prepare
 from repro.sim.trace import (ALL_APPS, GENERATED_GRAPHS, GRAPH_INPUTS,
                              make_trace, workload_key_error)
 
@@ -386,7 +385,7 @@ class BucketLanes:
     scan's geometry key), the study point indices riding this bucket
     (``lane_points``, in point order — lane ``i`` of the dispatch IS point
     ``lane_points[i]``), and the per-lane padded trace / hw / lazy triples
-    ready for :func:`repro.sim.engine.stack_traces` & co.  This is the
+    ready for :func:`repro.sim.engine.stack_lanes`.  This is the
     currency the serve layer's cross-request coalescer trades in: lanes
     from different requests with equal ``shape`` (+ spec + static flags)
     stack into one dispatch and split back by lane slice."""
@@ -597,9 +596,8 @@ class Study:
             real = sum(tts[w].num_lines for w, _, _ in sel)
             d = _mesh.devices_for(len(sel), resolved) if sel else 1
             buckets.append(dict(
-                num_lines=shape["num_lines"],
-                num_windows=shape["num_windows"],
-                num_kernels=shape["num_kernels"],
+                **shape,
+                spec=dataclasses.asdict(tts[idx[0]].spec),
                 workloads=[tts[i].name for i in idx],
                 lanes=len(sel),
                 devices=d,
@@ -636,11 +634,26 @@ class Study:
             self._bls = out
         return self._bls
 
-    def _make_point(self, j: int, results: dict[str, SimResult]) -> StudyPoint:
+    def _finalize_lanes(self, lane_points: list[int],
+                        accs: dict[str, dict]) -> list[StudyPoint]:
+        """One bucket's host accumulators as finalized points, in lane
+        order: lane ``pos`` of every ``accs`` array is study point
+        ``lane_points[pos]``; pad lanes past them are never read.  Every
+        lane passes the :func:`repro.core.mechanisms.finalize_result`
+        integrity sentinel; a poisoned lane raises ``ResultIntegrityError``
+        naming the workload, mechanism and field."""
         tts, hws, lazys = self.traces(), self.hw_points(), self.lazy_points()
-        w, h, li = self._lanes()[j]
-        return StudyPoint(workload=tts[w].name, hw_index=h, lazy_index=li,
-                          hw=hws[h], lazy=lazys[li], results=results)
+        lanes = self._lanes()
+        points = []
+        for pos, j in enumerate(lane_points):
+            w, h, li = lanes[j]
+            res = {m: finalize_result(tts[w].name, m,
+                                      {k: v[pos] for k, v in acc.items()})
+                   for m, acc in accs.items()}
+            points.append(StudyPoint(workload=tts[w].name, hw_index=h,
+                                     lazy_index=li, hw=hws[h], lazy=lazys[li],
+                                     results=res))
+        return points
 
     def points_from_lane_accs(self, accs: dict[str, dict]) -> ResultSet:
         """Split stacked accumulators back into this study's tagged points:
@@ -650,24 +663,15 @@ class Study:
         of cross-request coalescing (:mod:`repro.serve.coalesce`): the
         server slices the group dispatch's lane axis per request and hands
         each request's slab here.  Only valid for single-bucket studies
-        (the coalescer's admission rule), where lane order == point order.
-        Every lane passes the :func:`repro.core.mechanisms.finalize_result`
-        integrity sentinel; a poisoned lane raises ``ResultIntegrityError``
-        naming the workload, mechanism and field."""
+        (the coalescer's admission rule), where lane order == point order."""
         bls = self.bucket_lanes()
         if len(bls) != 1:
             raise ValueError(
                 f"points_from_lane_accs needs a single-bucket study, this "
                 f"one has {len(bls)} buckets (serve such studies "
                 f"uncoalesced)")
-        points = []
-        for pos, j in enumerate(bls[0].lane_points):
-            w = self._lanes()[j][0]
-            res = {m: finalize_result(self.traces()[w].name, m,
-                                      {k: v[pos] for k, v in acc.items()})
-                   for m, acc in accs.items()}
-            points.append(self._make_point(j, res))
-        return ResultSet(points, self.mechanisms)
+        return ResultSet(self._finalize_lanes(bls[0].lane_points, accs),
+                         self.mechanisms)
 
     # -- execution ----------------------------------------------------------
 
@@ -736,10 +740,9 @@ class Study:
 
     def _run_batched(self, on_dispatch=None,
                      devices: int | None = None) -> ResultSet:
-        tts, lanes = self.traces(), self._lanes()
         bls = self.bucket_lanes()
         resolved = _mesh.resolve_devices(devices)
-        points: list[StudyPoint | None] = [None] * len(lanes)
+        points: list[StudyPoint | None] = [None] * self.num_points
         with spans.span("run", study=self.trace_id):
             for bl in bls:
                 n = len(bl.traces)
@@ -756,31 +759,14 @@ class Study:
                 accs = _engine._sweep_accs(stacked, shw, self.mechanisms,
                                            scfg, boundary=boundary, devices=d)
                 with spans.span("finalize"):
-                    for pos, j in enumerate(bl.lane_points):
-                        w = lanes[j][0]
-                        res = {m: finalize_result(
-                                   tts[w].name, m,
-                                   {k: v[pos] for k, v in acc.items()})
-                               for m, acc in accs.items()}
-                        points[j] = self._make_point(j, res)
+                    for j, p in zip(bl.lane_points,
+                                    self._finalize_lanes(bl.lane_points,
+                                                         accs)):
+                        points[j] = p
         return ResultSet(points, self.mechanisms)
 
     def _stack_lanes(self, bl: BucketLanes, n: int, d: int):
         """One bucket's stacked (trace, hw, lazy) pytrees, its lane axis
         padded to the ``d``-device mesh multiple."""
-        width = _mesh.mesh_lane_width(n, d)
-        traces, hws, lazys = bl.traces, bl.hws, bl.lazys
-        if width > n:
-            # Mesh pad lanes: all-sentinel masked traces (zero
-            # contribution) carrying the study's static lazy flags so
-            # they ride the same compiled dataflow.  Appended past
-            # lane_points, so the result loop never reads them.
-            static = {f: getattr(self._lazys[0], f)
-                      for f in _engine._LAZY_STATIC_FIELDS}
-            pads = [dummy_lane_triple(traces[0].spec, bl.shape, static)
-                    for _ in range(width - n)]
-            traces = traces + [p[0] for p in pads]
-            hws = hws + [p[1] for p in pads]
-            lazys = lazys + [p[2] for p in pads]
-        return (_engine.neutral_trace(_engine.stack_traces(traces)),
-                _engine.stack_hw(hws), _engine.stack_lazy(lazys))
+        return _engine.stack_lanes(bl.traces, bl.hws, bl.lazys, bl.shape,
+                                   _mesh.mesh_lane_width(n, d))

@@ -77,7 +77,7 @@ STREAM_APPS = ("htap_stream",)
 MT_APPS = ("mtmix",)
 # Captured from live model execution (repro.capture), not synthesized:
 # first-class workloads everywhere a synthetic app name is accepted
-# (Study, run_batch, serve admission), but build_plan rejects them —
+# (Study, serve admission), but build_plan rejects them —
 # there is no synthesis plan to build.
 CAPTURE_APPS = ("capture/kv_serve", "capture/moe_experts",
                 "capture/lazy_embed")

@@ -1,8 +1,8 @@
 """Cross-engine equivalence harness for the geometry-bucketed batch engine.
 
-``repro.sim.engine.run_batch`` pads the whole workload fleet onto a few
-geometry buckets (``repro.sim.prep.bucket_traces``) and runs one compiled,
-vmapped window scan per (mechanism, bucket).  Padding bugs would silently
+The ``Study`` planner (``repro.sim.study``) pads the whole workload fleet
+onto a few geometry buckets (``repro.sim.prep.bucket_traces``) and runs one
+compiled, vmapped window scan per (mechanism, bucket).  Padding bugs would silently
 corrupt fleet averages, so the contract is *bit-exactness*: batched results
 must equal sequential ``run_all`` results on **every** ``SimResult`` field,
 for every workload in the full extended fleet (22 workloads), every
@@ -29,11 +29,11 @@ from repro.sim.engine import (
     MECHANISMS,
     lanes_per_step,
     run_all,
-    run_batch,
     stack_traces,
     sweep_cache_sizes,
 )
 from repro.sim.prep import bucket_bound, bucket_traces, pad_trace, prepare
+from repro.sim.study import Study
 from repro.sim.trace import all_workloads, make_trace
 
 HW = HWParams()
@@ -44,6 +44,12 @@ HW = HWParams()
 # also gates the committed BENCH_engine.json record in CI; before
 # bucketing the suite cost one compile per workload × mechanism = 132).
 MAX_FLEET_BUCKETS = 3
+
+
+def _batch(tts, **kw):
+    """One ``{mechanism: SimResult}`` dict per trace, in input order, from
+    the planner's batched engine."""
+    return [p.results for p in Study(workloads=tts, hw=HW, **kw).run().points]
 
 
 def _assert_equal(a, b, label):
@@ -67,7 +73,7 @@ def fleet():
 def batched(fleet):
     """Batched fleet results plus the compile-count deltas of the run."""
     before = sweep_cache_sizes()
-    results = run_batch(fleet, HW)
+    results = _batch(fleet)
     after = sweep_cache_sizes()
     return results, {m: after[m] - before[m] for m in after}
 
@@ -87,7 +93,7 @@ def test_fleet_buckets_and_compile_budget(fleet, batched):
 
 @pytest.mark.parametrize("mechanism", MECHANISMS)
 def test_batch_bit_exact_full_fleet(fleet, batched, mechanism):
-    """run_batch == sequential run_all on every SimResult field, every
+    """The batched planner == sequential run_all on every SimResult field, every
     workload (the compiled scans are shared module-wide, so this enumerates
     comparisons, not recompiles)."""
     results, _ = batched
@@ -101,7 +107,7 @@ def test_batch_bit_exact_full_commit_ablation(fleet):
     dataflow (accumulate-across-windows); the batched path must track it
     bit-exactly too."""
     cfg = LazyPIMConfig(partial_commits=False)
-    results = run_batch(fleet, HW, mechanisms=("lazypim",), lazy_cfg=cfg)
+    results = _batch(fleet, mechanisms=("lazypim",), lazy=cfg)
     for tt, br in zip(fleet, results):
         seq = simulate_lazypim(tt, HW, cfg)
         _assert_equal(seq, br["lazypim"], f"{tt.name}/lazypim-fullcommit")
@@ -148,7 +154,7 @@ def test_workload_exactly_at_bucket_max(small_pair):
     assert exact.num_lines == bound == bucket_bound(exact.num_lines)
     [(idx, padded)] = bucket_traces([exact])
     assert idx == [0] and padded[0].num_lines == bound
-    [br] = run_batch([exact], HW)
+    [br] = _batch([exact])
     seq = run_all(tt, HW)
     for m in seq:
         _assert_equal(seq[m], br[m], f"at-bound/{m}")
@@ -162,22 +168,27 @@ def test_singleton_bucket(small_pair):
     buckets = bucket_traces([small, other, big])
     sizes = sorted(len(idx) for idx, _ in buckets)
     assert sizes == [1, 2]
-    results = run_batch([small, other, big], HW, mechanisms=("cg", "lazypim"))
+    results = _batch([small, other, big], mechanisms=("cg", "lazypim"))
     for tt, br in zip((small, other, big), results):
         for m, r in br.items():
             _assert_equal(run_all(tt, HW, mechanisms=(m,))[m], r,
                           f"singleton/{tt.name}/{m}")
 
 
-def test_stack_traces_still_rejects_raw_geometry_mismatch(small_pair):
+@pytest.mark.parametrize("pair", ["lines", "kernels"])
+def test_stack_traces_still_rejects_raw_geometry_mismatch(pair, small_pair):
     """Bucketing routes mixed fleets around stack_traces; a *raw* mismatched
-    stack must still fail loudly rather than silently mis-shape."""
-    small, _ = small_pair
-    big = _small("htap128", None)
+    stack must still fail loudly rather than silently mis-shape — whether
+    the line counts differ or only the kernel counts."""
+    if pair == "lines":
+        a, b = small_pair[0], _small("htap128", None)
+    else:
+        a, b = (_small("pagerank", "arxiv", threads=4, num_kernels=k,
+                       scale=0.4) for k in (2, 3))
     with pytest.raises(ValueError, match="geometry differs"):
-        stack_traces([small, big])
+        stack_traces([a, b])
     # ... while the batch engine handles the same list through bucketing.
-    assert len(run_batch([small, big], HW, mechanisms=("nc",))) == 2
+    assert len(_batch([a, b], mechanisms=("nc",))) == 2
 
 
 def test_pad_trace_rejects_shrinking(small_pair):

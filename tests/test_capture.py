@@ -10,7 +10,7 @@
   recorder rejects ragged line counts;
 * first-class-workload integration: ``make_trace`` routing + naming
   ValueErrors, ``all_workloads(captured=)``, serve admission, and
-  bit-exact ``run_batch`` vs sequential ``run_all`` on captured traces;
+  bit-exact batched ``Study`` vs sequential ``run_all`` on captured traces;
 * fixed-seed determinism per (model seed, request-mix seed).
 """
 
@@ -32,8 +32,9 @@ from repro.capture.kv_serve import (
 from repro.capture.layout import LineLayout
 from repro.capture.recorder import split_step, subsample_even
 from repro.sim.costmodel import HWParams
-from repro.sim.engine import run_all, run_batch
+from repro.sim.engine import run_all
 from repro.sim.prep import bucket_bound, prepare
+from repro.sim.study import Study
 from repro.sim.trace import MAX_SIG_ADDRS, all_workloads, build_plan, make_trace
 
 HW = HWParams()
@@ -252,8 +253,9 @@ def test_run_batch_bit_exact(tiny_traces):
     """Captured traces through the geometry-bucketed batch engine ==
     the sequential reference engine, on every SimResult field."""
     tts = [prepare(tr) for tr in tiny_traces.values()]
-    batched = run_batch(tts, HW)
-    for tt, br in zip(tts, batched):
+    batched = Study(workloads=tts, hw=HW).run().points
+    for tt, p in zip(tts, batched):
+        br = p.results
         for m, r in br.items():
             seq = run_all(tt, HW, mechanisms=(m,))[m]
             da, db = dataclasses.asdict(seq), dataclasses.asdict(r)

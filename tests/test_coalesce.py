@@ -12,6 +12,7 @@ legs run one seed per matrix entry).
 import json
 import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -33,6 +34,7 @@ from repro.serve import (
     build_study,
     group_key,
     restart_server,
+    stack_group,
 )
 from repro.sim import engine as _engine
 from repro.sim.study import ResultSet, ResultSetSchemaError
@@ -164,6 +166,25 @@ def test_masked_pad_lanes_never_contribute():
     ref = build_study(SPEC_A).run("sequential")
     for r in out:
         _assert_rows_equal(r.results, ref)
+
+
+def test_planner_and_coalescer_stack_the_same_pytrees():
+    # One stacking seam: for the same three lanes at width 4 (the blessed
+    # width, and the 2-device mesh multiple), the planner's bucket stack
+    # and the coalescer's group stack are bit-identical, pad lane included.
+    st = build_study({**SPEC_A, "hw_grid": {"offchip_bw_gbs": [16.0, 32.0,
+                                                              64.0]}})
+    (bl,) = st.bucket_lanes()
+    planner = st._stack_lanes(bl, 3, 2)
+    stt, shw, scfg, _, width = stack_group(group_key(st), [(0, st)])
+    assert width == 4
+    coalescer = (stt, shw, scfg)
+    assert jax.tree.structure(planner) == jax.tree.structure(coalescer)
+    for a, b in zip(jax.tree.leaves(planner), jax.tree.leaves(coalescer)):
+        assert a.dtype == b.dtype and a.shape[0] == width
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    valid = np.asarray(stt.window_valid)
+    assert valid[:3].any(axis=1).all() and not valid[3].any()
 
 
 def test_multi_bucket_request_falls_back_to_single_request_path():

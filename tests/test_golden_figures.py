@@ -8,7 +8,7 @@ signature configuration shows up here as a tier-1 failure instead of a
 silently shifted benchmark table.
 
 ``tests/golden/fig7_batched_golden.json`` pins the SAME workloads through
-the geometry-bucketed batch engine (``repro.sim.engine.run_batch``): the
+the geometry-bucketed batch engine (the ``Study`` planner): the
 numbers must match the sequential-path golden to 1e-6 — same results,
 different engine — so a padding/bucketing regression surfaces here even if
 both goldens were regenerated together.
@@ -30,8 +30,9 @@ import pathlib
 import pytest
 
 from repro.sim.costmodel import HWParams
-from repro.sim.engine import run_all, run_batch, summarize
+from repro.sim.engine import run_all, summarize
 from repro.sim.prep import prepare
+from repro.sim.study import Study
 from repro.sim.trace import make_trace
 
 GOLDEN_DIR = pathlib.Path(__file__).parent / "golden"
@@ -48,7 +49,7 @@ def _current(engine: str = "sequential") -> dict:
     tts = [prepare(make_trace(app, g, threads=16))
            for app, g in GOLDEN_WORKLOADS]
     if engine == "batch":
-        results = run_batch(tts, hw)
+        results = [p.results for p in Study(workloads=tts, hw=hw).run().points]
     else:
         results = [run_all(tt, hw) for tt in tts]
     return {tt.name: summarize(r, hw) for tt, r in zip(tts, results)}

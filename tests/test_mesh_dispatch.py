@@ -15,6 +15,7 @@ device — so the policy tests below run everywhere and the differential
 tests skip themselves on single-device hosts.
 """
 
+import dataclasses
 import os
 import types
 
@@ -272,6 +273,29 @@ def test_warm_entries_record_mesh_routing():
         for e in study_warm_entries(st, devices=DEVICES):
             assert e["devices"] == mesh.devices_for(3, DEVICES)
             assert e["lanes"] % e["devices"] == 0
+
+
+def test_warm_entries_are_the_plan_rows():
+    # Warm rows read the plan: for a two-bucket study, one row per
+    # (bucket, mechanism) at the geometry the bucket's lanes are padded to,
+    # the lane count and mesh size the bucket routes to, and the study's
+    # spec and static flags — at one device and at every visible one.
+    st = _study(hw_points=3)
+    bls = st.bucket_lanes()
+    assert len(bls) == 2
+    static = _engine.lazy_static(st.lazy_points()[0])
+    for devices in sorted({1, DEVICES}):
+        plan = st.plan(devices)
+        want = []
+        for b, bl in zip(plan.buckets, bls):
+            d = mesh.devices_for(len(bl.traces), devices)
+            assert (b["devices"], b["padded_lanes"]) == (
+                d, mesh.mesh_lane_width(len(bl.traces), d))
+            want += [{**bl.shape, "mechanism": m, "lanes": b["padded_lanes"],
+                      "devices": d,
+                      "spec": dataclasses.asdict(bl.traces[0].spec),
+                      "lazy_static": static} for m in st.mechanisms]
+        assert study_warm_entries(st, devices=devices) == want
 
 
 def test_warm_replay_skips_overwide_mesh_entries(tmp_path):

@@ -21,14 +21,9 @@ from repro.core.coherence import LazyPIMConfig, simulate_lazypim
 from repro.core.signatures import default_spec
 from repro.sim import prep as P
 from repro.sim.costmodel import HWParams
-from repro.sim.engine import (
-    run_all,
-    run_sweep,
-    stack_hw,
-    stack_traces,
-    sweep_cache_sizes,
-)
+from repro.sim.engine import run_all, sweep_cache_sizes
 from repro.sim.prep import prepare
+from repro.sim.study import Study
 from repro.sim.trace import make_graph_trace, make_htap_trace
 
 HW = HWParams()
@@ -348,21 +343,15 @@ def test_run_sweep_matches_sequential_loop():
                                     scale=0.4))
            for t in threads]
     hws = [HWParams(cpu_cores=t, pim_cores=t) for t in threads]
+    study = Study(workloads=tts, hw=hws)
+    (bucket,) = study.plan(devices=1).buckets
     before = sweep_cache_sizes()
-    points = run_sweep(stack_traces(tts), stack_hw(hws))
+    points = study.run(devices=1).points
     after = sweep_cache_sizes()
     # one compile per mechanism for the whole 4-point sweep (measured)
+    assert bucket["lanes"] == len(threads)
     assert all(after[m] - before[m] <= 1 for m in after)
     for i in range(len(threads)):
         seq = run_all(tts[i], hws[i])
-        for m, r in points[i].items():
+        for m, r in points[i].results.items():
             _assert_results_equal(r, seq[m], f"sweep[{i}]/{m}")
-
-
-def test_stack_traces_rejects_geometry_mismatch():
-    a = prepare(make_graph_trace("pagerank", "arxiv", threads=4,
-                                 num_kernels=2, windows_per_kernel=2, scale=0.4))
-    b = prepare(make_graph_trace("pagerank", "arxiv", threads=4,
-                                 num_kernels=3, windows_per_kernel=2, scale=0.4))
-    with pytest.raises(ValueError):
-        stack_traces([a, b])

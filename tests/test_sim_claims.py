@@ -9,8 +9,9 @@ import pytest
 
 from repro.core.coherence import LazyPIMConfig, simulate_lazypim
 from repro.sim.costmodel import HWParams
-from repro.sim.engine import run_all, run_batch, summarize
+from repro.sim.engine import run_all, summarize
 from repro.sim.prep import prepare
+from repro.sim.study import Study
 from repro.sim.trace import all_workloads, make_trace
 
 HW = HWParams()
@@ -26,7 +27,7 @@ def matrix(request):
     regressed)."""
     tts = [prepare(make_trace(app, g, threads=16)) for app, g in all_workloads()]
     if request.param == "batch":
-        results = run_batch(tts, HW)
+        results = [p.results for p in Study(workloads=tts, hw=HW).run().points]
     else:
         results = [run_all(tt, HW) for tt in tts]
     return {tt.name: summarize(r, HW) for tt, r in zip(tts, results)}

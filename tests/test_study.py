@@ -3,10 +3,10 @@ compile-budget prediction, cross-engine bit-exactness, and the
 ``ResultSet`` container.
 
 The planner's numerics are additionally pinned by the long-standing
-cross-engine harnesses — ``run_batch`` is a thin wrapper over the planner,
-so ``tests/test_batch_engine.py`` (bit-exact vs sequential ``run_all`` on
-the full fleet) and ``tests/golden/fig7_batched_golden.json`` hold the
-redesign to the pre-study numbers field-for-field."""
+cross-engine harnesses — ``tests/test_batch_engine.py`` (bit-exact vs
+sequential ``run_all`` on the full fleet) and
+``tests/golden/fig7_batched_golden.json`` hold the redesign to the
+pre-study numbers field-for-field."""
 
 from __future__ import annotations
 
