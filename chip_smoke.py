@@ -22,8 +22,9 @@ Phases, in order, each printing one JSON line of its own results:
    repeat.
 
 With ``--chips 4`` only phase 4 runs, at ``devices=4`` and at
-``devices=1``: bit-exact on every field, with the device of every output
-shard printed.  Any exception or mismatch exits non-zero.  The last line
+``devices=1``: bit-exact on every field, with the device of every shard
+of the stacked input and of the output printed; each must span the four
+devices.  Any exception or mismatch exits non-zero.  The last line
 of standard output is ``{"ok": true, "device": {...}}`` and nothing else.
 
 The script reads only tracked files and what it generates from seeds.
@@ -230,24 +231,30 @@ def phase_served(specs=SERVED_SPECS) -> dict:
                  stats=dict(server.stats), peak_bytes=_peak_bytes())
 
 
+def _shards(leaf) -> list[dict]:
+    return [{"device": str(s.device), "lanes": s.data.shape[0]}
+            for s in leaf.addressable_shards]
+
+
 def shard_placement(study, devices: int) -> dict:
-    """Where the output shards of one sharded dispatch live: the stacked
-    accumulator of the ``cpu`` scan over the study's single bucket, its
-    lanes stacked as the planner stacks them."""
+    """Where one sharded dispatch's shards live: the stacked input (the
+    trace's ``window_valid``), as the planner stacks the study's single
+    bucket, and the accumulator the ``cpu`` scan returns from it."""
     from repro.sim import engine
 
     (bl,) = study.bucket_lanes()
     stt, shw, _ = study._stack_lanes(bl, len(bl.traces), devices)
     acc = engine._sweep_fn_mesh("cpu", devices)(stt, shw)
     leaf = next(iter(acc.values()))
-    return {"sharding": str(leaf.sharding),
-            "shards": [{"device": str(s.device), "lanes": s.data.shape[0]}
-                       for s in leaf.addressable_shards]}
+    return {"sharding": str(leaf.sharding), "shards": _shards(leaf),
+            "input_sharding": str(stt.window_valid.sharding),
+            "input_shards": _shards(stt.window_valid)}
 
 
 def phase_mesh(study, devices: int) -> dict:
     """Phase 4 at ``devices`` and at 1: bit-exact on every field, and the
-    output shards spread over ``devices`` distinct devices."""
+    stacked input's and the output's shards each spread over ``devices``
+    distinct devices."""
     _, synth_s = _timed(study.traces)
     walls = {}
     results = {}
@@ -260,10 +267,11 @@ def phase_mesh(study, devices: int) -> dict:
         raise SmokeFailure(f"mesh: devices={devices} != devices=1: "
                            + "; ".join(bad))
     placement = shard_placement(study, devices)
-    used = {s["device"] for s in placement["shards"]}
-    if len(used) != devices:
-        raise SmokeFailure(f"mesh: output shards on {sorted(used)}, want "
-                           f"{devices} distinct devices")
+    for key in ("input_shards", "shards"):
+        used = {s["device"] for s in placement[key]}
+        if len(used) != devices:
+            raise SmokeFailure(f"mesh: {key} on {sorted(used)}, want "
+                               f"{devices} distinct devices")
     return _emit("mesh", lines=study.traces()[0].num_lines,
                  lanes=study.num_points, synth_s=synth_s,
                  walls={str(d): w for d, w in walls.items()},

@@ -96,10 +96,14 @@ def d2h(x) -> np.ndarray:
     return np.asarray(x)
 
 
-def h2d(x, dtype=None) -> jax.Array:
-    """``jnp.asarray(x, dtype)``; a host ``x`` counts the bytes put on the
-    device."""
-    out = jnp.asarray(x, dtype)
+def h2d(x, dtype=None, sharding=None) -> jax.Array:
+    """``jnp.asarray(x, dtype)``, or with a ``sharding`` a
+    ``jax.device_put`` that puts each shard on its own device; a host ``x``
+    counts the bytes put on the devices, all shards together."""
+    if sharding is None:
+        out = jnp.asarray(x, dtype)
+    else:
+        out = jax.device_put(np.asarray(x, dtype), sharding)
     stack = _stack()
     if stack and not isinstance(x, jax.Array):
         stack[-1].h2d_bytes += out.nbytes

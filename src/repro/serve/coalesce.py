@@ -149,12 +149,12 @@ def stack_group(key: GroupKey, members: list[tuple[int, Study]],
     """Build the stacked (trace, hw, lazy) pytrees for one coalesced
     dispatch: member lanes in member order, stacked by
     :func:`repro.sim.engine.stack_lanes` at the blessed width (the smallest
-    one a ``devices``-wide lane mesh divides).  Returns
-    ``(stt, shw, scfg, slices, width)``."""
+    one a ``devices``-wide lane mesh divides), each lane shard put on its
+    own device.  Returns ``(stt, shw, scfg, slices, width)``."""
     traces, hws, lazys, slices = group_lanes(members)
     width = blessed_width(len(traces), devices)
     stt, shw, scfg = _engine.stack_lanes(traces, hws, lazys, dict(key.shape),
-                                         width)
+                                         width, devices)
     return stt, shw, scfg, slices, width
 
 

@@ -106,16 +106,22 @@ def dummy_stacked(entry: dict):
     """Build the (stacked trace, stacked hw, stacked lazy) triple whose jit
     key equals a manifest entry's compile key: exact bucket geometry and
     lane count, every lane the pad triple of
-    :func:`repro.sim.prep.dummy_lane_triple`.  The trace is broadcast with
-    jnp ops, so ``jax.eval_shape`` gives the entry's argument shapes
-    without materializing any lane (``tests/test_tpu_compile.py``)."""
+    :func:`repro.sim.prep.dummy_lane_triple`, placed as
+    :func:`repro.sim.engine.stack_lanes` places a dispatch on the entry's
+    lane mesh (a different placement would be a different compile key).
+    The trace is broadcast with jnp ops, so ``jax.eval_shape`` gives the
+    entry's argument shapes without materializing any lane
+    (``tests/test_tpu_compile.py``)."""
     tt, hw, cfg = dummy_lane_triple(
         SignatureSpec(**entry["spec"]),
         {k: entry[k] for k in _GEOMETRY_KEYS}, entry["lazy_static"])
     lanes = entry["lanes"]
+    sharding = _mesh.lane_sharding(int(entry.get("devices", 1)))
     stt = jax.tree.map(lambda x: jnp.broadcast_to(x, (lanes, *x.shape)), tt)
-    return (stt, _engine.stack_hw([hw] * lanes),
-            _engine.stack_lazy([cfg] * lanes))
+    if sharding is not None:
+        stt = jax.device_put(stt, sharding)
+    return (stt, _engine.stack_hw([hw] * lanes, sharding),
+            _engine.stack_lazy([cfg] * lanes, sharding))
 
 
 class WarmCache:

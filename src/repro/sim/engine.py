@@ -56,6 +56,7 @@ from repro.core.mechanisms import (
 )
 from repro.runtime import spans
 from repro.sim.costmodel import HWParams, hw_leaf_dtypes
+from repro.sim.mesh import lane_sharding, shard_lanes
 from repro.sim.prep import (
     TRACE_DATA_FIELDS,
     TraceTensors,
@@ -100,20 +101,21 @@ def run_all(
 # ---------------------------------------------------------------------------
 
 
-def stack_hw(hws: list[HWParams]) -> HWParams:
+def stack_hw(hws: list[HWParams], sharding=None) -> HWParams:
     """Stack a list of HWParams into one pytree with (S,)-shaped leaves.
 
     Leaf dtypes come from the explicit declaration
     :func:`repro.sim.costmodel.hw_leaf_dtypes` (int32 counts/capacities,
     float32 everything else), so sweeps that write ``offchip_bw_gbs=16``
     and ``offchip_bw_gbs=16.0`` hit the same compiled function.  Every
-    field round-trips at its declared dtype (``tests/test_study.py``)."""
+    field round-trips at its declared dtype (``tests/test_study.py``).
+    ``sharding`` places the leaves (:func:`stack_lanes`)."""
     dtypes = hw_leaf_dtypes()
     kw = {}
     for f in dataclasses.fields(HWParams):
         kw[f.name] = spans.h2d(np.asarray(
             [getattr(h, f.name) for h in hws],
-            dtype=np.dtype(dtypes[f.name])))
+            dtype=np.dtype(dtypes[f.name])), sharding=sharding)
     return HWParams(**kw)
 
 
@@ -132,13 +134,14 @@ def lazy_static(cfg: LazyPIMConfig) -> dict:
     return {f: getattr(cfg, f) for f in _LAZY_STATIC_FIELDS}
 
 
-def stack_lazy(cfgs: list[LazyPIMConfig]) -> LazyPIMConfig:
+def stack_lazy(cfgs: list[LazyPIMConfig], sharding=None) -> LazyPIMConfig:
     """Stack LazyPIMConfigs into one pytree with (S,)-shaped numeric leaves.
 
     Only the traced knobs may vary: the static flags (``partial_commits``,
     ``cpuws_regs``, ``max_rollbacks``) select a different compiled dataflow,
     so a stack mixing them is rejected with a ``ValueError`` naming the
     offending entry — run one study/sweep per static-flag combo instead.
+    ``sharding`` places the leaves (:func:`stack_lanes`).
     """
     c0 = cfgs[0]
     for i, c in enumerate(cfgs[1:], start=1):
@@ -152,11 +155,12 @@ def stack_lazy(cfgs: list[LazyPIMConfig]) -> LazyPIMConfig:
     kw = lazy_static(c0)
     for name, dt in _LAZY_DATA_DTYPES.items():
         kw[name] = spans.h2d(np.asarray(
-            [getattr(c, name) for c in cfgs], dtype=np.dtype(dt)))
+            [getattr(c, name) for c in cfgs], dtype=np.dtype(dt)),
+            sharding=sharding)
     return LazyPIMConfig(**kw)
 
 
-def stack_traces(tts: list[TraceTensors]) -> TraceTensors:
+def stack_traces(tts: list[TraceTensors], sharding=None) -> TraceTensors:
     """Stack same-geometry TraceTensors into one pytree with a leading sweep
     axis on every tensor leaf.
 
@@ -168,7 +172,8 @@ def stack_traces(tts: list[TraceTensors]) -> TraceTensors:
     shared bucket shapes first.  ``name``/``threads`` are
     taken from the first trace; the locality constants (``cpu_reuse``,
     ``cpu_priv_miss_rate``) are traced scalar leaves and stack per point
-    like every other tensor.
+    like every other tensor.  ``sharding`` places the leaves
+    (:func:`stack_lanes`).
     """
     t0 = tts[0]
     for t in tts[1:]:
@@ -186,7 +191,8 @@ def stack_traces(tts: list[TraceTensors]) -> TraceTensors:
         # of device arrays issues expand_dims+concatenate per *element*,
         # whose dispatch overhead dominates wide (coalesced) stacks.
         fields[key] = spans.h2d(
-            np.stack([spans.d2h(getattr(t, key)) for t in tts]))
+            np.stack([spans.d2h(getattr(t, key)) for t in tts]),
+            sharding=sharding)
     return TraceTensors(**fields)
 
 
@@ -196,6 +202,7 @@ def stack_lanes(
     lazys: list[LazyPIMConfig],
     shape: dict[str, int],
     width: int,
+    devices: int = 1,
 ) -> tuple[TraceTensors, HWParams, LazyPIMConfig]:
     """One geometry bucket's lanes as the stacked (trace, hw, lazy) pytrees
     of one dispatch: the lanes, all padded to the bucket ``shape`` (the
@@ -205,7 +212,14 @@ def stack_lanes(
     ``width`` is the caller's compile-key policy — the planner's mesh
     multiple, the coalescer's blessed width.  Every batched caller stacks
     through here, so a change to how lanes become one dispatch is a change
-    to this function."""
+    to this function.
+
+    ``devices`` is the lane-mesh size the stacks are dispatched to
+    (``width`` a multiple of it).  Above one, every leaf is put with
+    :func:`repro.sim.mesh.lane_sharding`: each device receives only its
+    ``width / devices`` lanes, committed with the sharding the mesh
+    scans take, so no scan moves its input again.  One device keeps the
+    uncommitted default-device put."""
     pad = width - len(traces)
     if pad:
         tt, hw, cfg = dummy_lane_triple(traces[0].spec, shape,
@@ -213,8 +227,9 @@ def stack_lanes(
         traces = traces + [tt] * pad
         hws = hws + [hw] * pad
         lazys = lazys + [cfg] * pad
-    return (neutral_trace(stack_traces(traces)), stack_hw(hws),
-            stack_lazy(lazys))
+    sharding = lane_sharding(devices)
+    return (neutral_trace(stack_traces(traces, sharding)),
+            stack_hw(hws, sharding), stack_lazy(lazys, sharding))
 
 
 # ---------------------------------------------------------------------------
@@ -280,8 +295,6 @@ def _sweep_fn_sharded(mechanism: str, devices: int):
     (mechanism, device count) — the mesh sibling of :func:`_sweep_fn`,
     with its own jit cache: one compile key space per device count, which
     is exactly what ``Study.plan(devices=...)`` predicts."""
-    from repro.sim.mesh import shard_lanes
-
     _MESH_DEVICE_COUNTS.add(devices)
     return jax.jit(shard_lanes(_lane_fn(mechanism), devices))
 

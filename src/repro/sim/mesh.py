@@ -5,7 +5,7 @@ stacked lane axis (:mod:`repro.sim.study`), and lanes are embarrassingly
 parallel — no mechanism scan communicates across lanes.  This module is
 the thin policy layer that spreads that axis over a 1-D ``lanes`` device
 mesh (:func:`repro.launch.mesh.make_lane_mesh`) via ``shard_map``, with
-three invariants the planner and the serve layer lean on:
+four invariants the planner and the serve layer lean on:
 
 * **The single-device path is byte-identical.**  ``devices=1`` selects
   the exact pre-mesh jitted functions (``engine._sweep_fn`` — the same
@@ -25,6 +25,10 @@ three invariants the planner and the serve layer lean on:
   lanes than devices runs on the largest power-of-two subset its lanes
   fill (:func:`devices_for`) instead of padding a 1-lane dispatch out to
   the full mesh.
+* **Each lane shard is put on its own device.**  A ``devices > 1``
+  dispatch's stacks are placed with :func:`lane_sharding`
+  (``engine.stack_lanes``): every device receives only its lanes, and
+  the scans take their inputs where they already are.
 
 Simulated multi-device CPU runs force the device count *before* jax
 initializes (``--xla_force_host_platform_device_count``; precedent in
@@ -59,14 +63,14 @@ def force_host_device_count() -> None:
 force_host_device_count()
 
 import jax  # noqa: E402  (the env translation above must precede this)
-from jax.sharding import PartitionSpec  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec  # noqa: E402
 
 from repro.launch.mesh import LANE_AXIS, make_lane_mesh  # noqa: E402
 
 __all__ = [
     "LANE_AXIS", "MESH_ENV_VAR", "available_devices", "resolve_devices",
-    "devices_for", "mesh_lane_width", "lane_mesh", "shard_lanes",
-    "force_host_device_count",
+    "devices_for", "mesh_lane_width", "lane_mesh", "lane_sharding",
+    "shard_lanes", "force_host_device_count",
 ]
 
 
@@ -121,6 +125,17 @@ def mesh_lane_width(lanes: int, devices: int) -> int:
 def lane_mesh(devices: int):
     """The (cached) 1-D ``lanes`` mesh over the first ``devices`` devices."""
     return make_lane_mesh(devices)
+
+
+def lane_sharding(devices: int):
+    """Where a ``devices``-wide dispatch's stacked leaves are put: each
+    lane shard on its own device, committed with exactly the sharding
+    :func:`shard_lanes` takes, so no scan moves its input again.  ``None``
+    for one device: the uncommitted default-device put of the
+    byte-identical single-device path."""
+    if devices <= 1:
+        return None
+    return NamedSharding(lane_mesh(devices), PartitionSpec(LANE_AXIS))
 
 
 def shard_lanes(fn, devices: int):

@@ -747,7 +747,7 @@ class Study:
             for bl in bls:
                 n = len(bl.traces)
                 d = _mesh.devices_for(n, resolved)
-                with spans.span("stack"):
+                with spans.span("stack", devices=d):
                     stacked, shw, scfg = self._stack_lanes(bl, n, d)
                 boundary = None
                 if on_dispatch is not None:
@@ -767,6 +767,7 @@ class Study:
 
     def _stack_lanes(self, bl: BucketLanes, n: int, d: int):
         """One bucket's stacked (trace, hw, lazy) pytrees, its lane axis
-        padded to the ``d``-device mesh multiple."""
+        padded to the ``d``-device mesh multiple and each lane shard put on
+        its own device."""
         return _engine.stack_lanes(bl.traces, bl.hws, bl.lazys, bl.shape,
-                                   _mesh.mesh_lane_width(n, d))
+                                   _mesh.mesh_lane_width(n, d), d)

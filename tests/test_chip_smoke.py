@@ -132,6 +132,7 @@ def test_phase_mesh_on_four_virtual_devices():
                          timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
-    devices = {s["device"] for s in rec["placement"]["shards"]}
-    assert len(devices) == 4
-    assert all(s["lanes"] == 1 for s in rec["placement"]["shards"])
+    for key in ("input_shards", "shards"):
+        devices = {s["device"] for s in rec["placement"][key]}
+        assert len(devices) == 4, key
+        assert all(s["lanes"] == 1 for s in rec["placement"][key]), key

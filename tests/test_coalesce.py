@@ -37,6 +37,7 @@ from repro.serve import (
     stack_group,
 )
 from repro.sim import engine as _engine
+from repro.sim.mesh import mesh_lane_width
 from repro.sim.study import ResultSet, ResultSetSchemaError
 
 SEEDS = ([int(os.environ["REPRO_CHAOS_SEED"])]
@@ -170,12 +171,15 @@ def test_masked_pad_lanes_never_contribute():
 
 def test_planner_and_coalescer_stack_the_same_pytrees():
     # One stacking seam: for the same three lanes at width 4 (the blessed
-    # width, and the 2-device mesh multiple), the planner's bucket stack
+    # width, and the 2-device mesh multiple), the planner's bucket lanes
     # and the coalescer's group stack are bit-identical, pad lane included.
+    # The planner's own call would place its stack on two devices, which
+    # this host may lack; placement is tests/test_mesh_dispatch.py's.
     st = build_study({**SPEC_A, "hw_grid": {"offchip_bw_gbs": [16.0, 32.0,
                                                               64.0]}})
     (bl,) = st.bucket_lanes()
-    planner = st._stack_lanes(bl, 3, 2)
+    planner = _engine.stack_lanes(bl.traces, bl.hws, bl.lazys, bl.shape,
+                                  mesh_lane_width(3, 2))
     stt, shw, scfg, _, width = stack_group(group_key(st), [(0, st)])
     assert width == 4
     coalescer = (stt, shw, scfg)

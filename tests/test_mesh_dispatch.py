@@ -1,4 +1,6 @@
-"""Mesh-sharded lane dispatch: routing/padding policy, bit-exactness of
+"""Mesh-sharded lane dispatch: routing/padding policy, each lane shard
+stacked onto its own device (checked in a child on four virtual devices,
+whatever this process sees), bit-exactness of
 the sharded planner vs the byte-identical single-device reference (all 6
 mechanisms x buckets x partial/full-commit, non-divisible lane counts),
 mesh-transparent coalesced serve storms, and warm-manifest device
@@ -16,11 +18,17 @@ tests skip themselves on single-device hosts.
 """
 
 import dataclasses
+import json
 import os
+import pathlib
+import subprocess
+import sys
 import types
 
+import jax
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec, SingleDeviceSharding
 
 from repro.sim import mesh  # noqa: F401  (env translation precedes jax init)
 
@@ -70,7 +78,10 @@ def _study(partial_commits=True, hw_points=3):
 
 
 def _assert_rows_equal(a, b):
-    ra, rb = a.to_rows(), b.to_rows()
+    _assert_row_lists_equal(a.to_rows(), b.to_rows())
+
+
+def _assert_row_lists_equal(ra, rb):
     assert len(ra) == len(rb)
     for x, y in zip(ra, rb):
         assert x.keys() == y.keys()
@@ -178,6 +189,105 @@ def test_plan_predicts_device_routing_and_padding():
         # (mechanism, bucket) whichever mesh variant it lands in.
         assert plan.compiles_per_mechanism == \
             _study().plan(devices=1).compiles_per_mechanism
+
+
+# -- placement: each lane shard stacked onto its own device ------------------
+
+
+def test_single_device_stacks_stay_uncommitted_on_the_default_device():
+    # The byte-identical fallback: at d=1 every stacked leaf is the
+    # uncommitted default-device put the pre-mesh path made.
+    st = _study()
+    for bl in st.bucket_lanes():
+        n = len(bl.traces)
+        for leaf in jax.tree_util.tree_leaves(st._stack_lanes(bl, n, 1)):
+            assert not leaf.committed
+            assert isinstance(leaf.sharding, SingleDeviceSharding)
+            assert leaf.sharding.device_set == {jax.devices()[0]}
+            assert leaf.shape[0] == n
+
+
+_PLACEMENT_SCRIPT = r"""
+import json, pathlib, tempfile
+import jax
+from repro.sim import mesh  # the forced device count precedes jax init
+from repro.runtime import spans
+from repro.serve.coalesce import group_key, stack_group
+from repro.serve.warm import WarmCache, study_warm_entries
+from repro.sim import engine
+from repro.sim.study import Study, grid, workload
+
+def mk():
+    return Study(workloads=[workload("htap128", scale=0.004, num_kernels=3,
+                                     windows_per_kernel=2)],
+                 hw=grid(offchip_bw_gbs=[16.0, 32.0, 48.0, 64.0]),
+                 mechanisms=("cpu", "lazypim"))
+
+def where(x):
+    named = isinstance(x.sharding, jax.sharding.NamedSharding)
+    return {"named": named, "committed": x.committed,
+            "axes": list(x.sharding.mesh.axis_names) if named else None,
+            "spec": str(x.sharding.spec) if named else None,
+            "shards": sorted([s.device.id, s.data.shape[0]]
+                             for s in x.addressable_shards)}
+
+st = mk()
+(bl,) = st.bucket_lanes()
+with tempfile.TemporaryDirectory() as d:
+    wc = WarmCache(pathlib.Path(d) / "warm")
+    wc.record_entries(study_warm_entries(st, devices=4))
+    wc.warm_from_manifest()
+    planner = st._stack_lanes(bl, 4, 4)
+    coalesced = stack_group(group_key(st), [(0, st)], devices=4)[:3]
+    placed = [where(x) for x in jax.tree_util.tree_leaves((planner, coalesced))]
+    jax.profiler.start_trace(d)
+    try:
+        one = mk().run(devices=1)
+        before = engine.sweep_cache_sizes(st.mechanisms)
+        with jax.transfer_guard_device_to_device("disallow"):
+            four = mk().run(devices=4)
+        after = engine.sweep_cache_sizes(st.mechanisms)
+    finally:
+        jax.profiler.stop_trace()
+stacks = [[p[3]["devices"], p[3]["d2h_bytes"], p[3]["h2d_bytes"]]
+          for p in spans.recorded() if p[2] == "repro:stack"]
+print(json.dumps({"devices": len(jax.devices()), "placed": placed,
+                  "one": one.to_rows(), "four": four.to_rows(),
+                  "stacks": stacks,
+                  "compiles": sum(after.values()) - sum(before.values())}))
+"""
+
+
+def test_sharded_stacks_are_placed_per_shard_and_never_move_again():
+    # 4 lanes on 4 virtual devices: every leaf the planner and the
+    # coalescer stack arrives committed with the lane mesh's sharding, one
+    # lane per device, so the sharded scans run under a device-to-device
+    # transfer guard (a stack put whole on device 0 is resharded by every
+    # scan) and compile nothing after a warm replay placed the same way;
+    # the results equal devices=1 bit for bit, and the stack moves the
+    # same bytes as the single-device stack.
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", mesh.MESH_ENV_VAR: "4",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+           "PYTHONPATH": os.pathsep.join(
+               filter(None, [str(src), os.environ.get("PYTHONPATH")]))}
+    proc = subprocess.run([sys.executable, "-c", _PLACEMENT_SCRIPT],
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    rec = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert rec["devices"] == 4
+    assert rec["placed"]
+    for leaf in rec["placed"]:
+        assert leaf["named"] and leaf["committed"]
+        assert leaf["axes"] == [mesh.LANE_AXIS]
+        assert leaf["spec"] == str(PartitionSpec(mesh.LANE_AXIS))
+        assert leaf["shards"] == [[i, 1] for i in range(4)]
+    _assert_row_lists_equal(rec["one"], rec["four"])
+    (one,) = [s[1:] for s in rec["stacks"] if s[0] == 1]
+    (four,) = [s[1:] for s in rec["stacks"] if s[0] == 4]
+    assert four == one and one[0] > 0 and one[1] > 0
+    assert rec["compiles"] == 0
 
 
 # -- differential: sharded vs single-device, bit-exact -----------------------
